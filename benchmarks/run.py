@@ -38,6 +38,9 @@ def main() -> None:
 
     from repro.core import CodesignConfig
     from repro.core.swspace import default_backend
+    from repro.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     config = None
     if args.config is not None:

@@ -22,7 +22,10 @@ def main():
     args = ap.parse_args()
 
     from repro.configs.base import SHAPES, get_config
+    from repro.jax_cache import enable_compile_cache
     from repro.core.autotune import TuneConfig, TuneSpace, autotune
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     shape = SHAPES[args.shape]

@@ -22,6 +22,7 @@ import dataclasses
 from repro.core import (BACKENDS, PRUNE_MODES, STRATEGIES, CodesignConfig,
                         CodesignEngine, EngineConfig, HWSearchConfig,
                         SWSearchConfig)
+from repro.jax_cache import enable_compile_cache
 from repro.timeloop import MODEL_LAYERS, eyeriss_baseline_edp
 
 
@@ -61,6 +62,7 @@ def main():
     ap.add_argument("--save-config", default=None, metavar="PATH",
                     help="write the CodesignConfig that ran as JSON")
     args = ap.parse_args()
+    enable_compile_cache()
 
     layers = MODEL_LAYERS["dqn"]
     base = eyeriss_baseline_edp(layers, num_pes=168, budget=4000)

@@ -24,6 +24,7 @@ import json
 from repro.core import (BACKENDS, CodesignConfig, CodesignEngine,
                         EngineConfig, HWSearchConfig, ServiceConfig,
                         SWSearchConfig)
+from repro.jax_cache import enable_compile_cache
 from repro.service import CodesignService, ServiceRequest
 from repro.workloads import (PortfolioConfig, portfolio_codesign,
                              resolve_workload)
@@ -58,6 +59,7 @@ def main():
                     help="round-trip the portfolio through the co-design "
                          "service JSON surface and check parity")
     args = ap.parse_args()
+    enable_compile_cache()
 
     workloads = tuple(w.strip() for w in args.workloads.split(","))
     weights = (tuple(float(w) for w in args.weights.split(","))

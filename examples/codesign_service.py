@@ -35,6 +35,7 @@ import tempfile
 from repro.core import (BACKENDS, EXECUTOR_KINDS, CodesignConfig,
                         EngineConfig, ExecutorConfig, HWSearchConfig,
                         ServiceConfig, SWSearchConfig)
+from repro.jax_cache import enable_compile_cache
 from repro.service import CodesignService, ServiceRequest, make_executor
 from repro.timeloop import MODEL_LAYERS
 
@@ -115,6 +116,7 @@ def main():
                     help="process-executor pool width (0 = one per core, "
                          "capped at 4)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     store_dir = args.store_dir or tempfile.mkdtemp(prefix="design_store_")
     history_dir = (tempfile.mkdtemp(prefix="trial_history_")

@@ -10,10 +10,12 @@ search.
 import numpy as np
 
 from repro.core import SoftwareSpace, bo_maximize, random_search
+from repro.jax_cache import enable_compile_cache
 from repro.timeloop import PAPER_WORKLOADS, evaluate, eyeriss_168
 
 
 def main():
+    enable_compile_cache()
     hw = eyeriss_168()
     layer = PAPER_WORKLOADS["ResNet-K2"]
     space = SoftwareSpace(hw, layer)

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.data.pipeline import DataConfig, SyntheticSource
+from repro.jax_cache import enable_compile_cache
 from repro.launch import steps as S
 from repro.optim import adamw
 from repro.runtime.fault_tolerance import ResilientLoop
@@ -40,6 +41,7 @@ def main():
     ap.add_argument("--inject-fault", type=int, default=150,
                     help="step at which to inject a fault (-1 to disable)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = CFG_100M
     shape = ShapeConfig("train100m", args.seq, args.batch, "train")

@@ -51,12 +51,12 @@ def make_acquisition_device(name: str, lam: float = 1.0):
     import side effect, just in the other direction)."""
     import math
 
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     from jax.scipy.special import erf
 
     def ei(mu, var, best):
-        with enable_x64():
+        with jax.enable_x64(True):
             sigma = jnp.sqrt(var)
             z = (mu - best) / jnp.maximum(sigma, 1e-12)
             pdf = jnp.exp(-0.5 * z * z) / math.sqrt(2.0 * math.pi)
@@ -64,7 +64,7 @@ def make_acquisition_device(name: str, lam: float = 1.0):
             return (mu - best) * cdf + sigma * pdf
 
     def lcb(mu, var, best):
-        with enable_x64():
+        with jax.enable_x64(True):
             return mu + lam * jnp.sqrt(var)
 
     if name == "ei":

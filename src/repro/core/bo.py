@@ -57,6 +57,8 @@ import contextlib
 import dataclasses
 from typing import Any, Callable, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.acquisition import make_acquisition, make_acquisition_device
@@ -483,11 +485,14 @@ class BOLoop:
                     self.cfg.acquisition, self.cfg.lam)
             pool = self._sample_valid_pool(self.cfg.pool_size)
             feats_dev = self.space.features_batch_device(pool)
-            mu, var = self._model.posterior_device(feats_dev)
-            utility = self._acq_dev(mu, var, self.result.best_value)
-            if self._classifier is not None:
-                utility = utility * self._classifier.prob_feasible_device(
-                    feats_dev)
+            # The posterior is f64: every op on it stays in scoped x64, or
+            # jax truncates it to f32 outside the scope.
+            with jax.enable_x64(True):
+                mu, var = self._model.posterior_device(feats_dev)
+                utility = self._acq_dev(mu, var, self.result.best_value)
+                if self._classifier is not None:
+                    utility = utility * self._classifier.prob_feasible_device(
+                        feats_dev)
             self._plan = {"kind": "scored", "t": t, "pool": pool,
                           "feats": None, "feats_dev": feats_dev,
                           "utility": utility, "k_cap": None, "device": True}
@@ -587,11 +592,12 @@ class BOLoop:
             return
         pool, utility = plan["pool"], plan["utility"]
         if plan["device"]:
-            import jax.numpy as jnp
-
+            # Host copies at the boundary: the f64 utility never meets a jnp
+            # op outside scoped x64.
+            utility = np.asarray(utility)
             _prefetch_topk(self.space, pool, utility)
-            i_best = int(jnp.argmax(utility))
-            feat_row = np.asarray(plan["feats_dev"][i_best], dtype=np.float64)
+            i_best = int(np.argmax(utility))
+            feat_row = np.asarray(plan["feats_dev"], dtype=np.float64)[i_best]
             self._observe(pool[i_best], feats=feat_row)
             self._rank1_update(feat_row)
         else:
@@ -976,15 +982,12 @@ def bo_maximize_many(
                 runs = cohort.runs
                 best = np.array([[results[k].best_value] for k in runs])
                 if use_device:
-                    import jax.numpy as jnp
-                    from jax.experimental import enable_x64
-
                     # The stacked features are f64 device arrays; every op on
                     # them (gathers included) must trace under scoped x64 --
                     # and the incumbents must enter as f64 (like the
                     # sequential path's Python-float best) or EI loses
                     # precision.
-                    with enable_x64():
+                    with jax.enable_x64(True):
                         sub = feats_dev[jnp.asarray(runs)]
                     if cohort.clf is None:
                         # Hot case (the inner software searches sample
@@ -994,7 +997,7 @@ def bo_maximize_many(
                         idx, rows = cohort.model.score_device(
                             sub, best, acquisition, lam)
                     else:
-                        with enable_x64():
+                        with jax.enable_x64(True):
                             mu, var = cohort.model.posterior_device(sub)
                             util = acq_dev(mu, var, jnp.asarray(best))
                             pos = jnp.asarray(

@@ -306,6 +306,11 @@ class EngineConfig:
                 f"strategy={self.strategy!r} requires use_cache=True: the "
                 "fan-out prefills the (hw, layer) cache that probe evaluation "
                 "reads")
+        if self.executor.kind == "process" and self.resolve_backend() == "jax":
+            raise ValueError(
+                "executor kind 'process' runs numpy-backend searches only: "
+                "its workers are pinned to the host CPU, and the accelerator "
+                "belongs to the learner process")
 
     def resolve_backend(self) -> str:
         from repro.core.swspace import default_backend

@@ -19,11 +19,13 @@ logdet terms), so each slice of a stack reproduces the corresponding
 individual `GP` fit regardless of how runs are padded to the shared bucket.
 
 The Cholesky solves need float64, but that is scoped to the GP computations via
-the `jax.experimental.enable_x64` context -- importing this module does NOT flip
-the process-global x64 flag (which would silently force every other JAX program
-in the process, e.g. the float32 Pallas evaluation engine, to f64).  The fitted
-state is held as f64 device arrays, which flow through jit fine regardless of
-the global flag.
+the `jax.enable_x64(True)` context -- importing this module does NOT flip the
+process-global x64 flag (which would silently force every other JAX program in
+the process, e.g. the float32 Pallas evaluation engine, to f64).  The fitted
+state is held as f64 device arrays, and every jnp op that touches them, or the
+f64 posteriors and utilities they produce, must run inside that scope: outside
+it jax rejects or truncates f64 operands.  Callers that leave the scope take
+host copies (`np.asarray`) at the boundary.
 """
 
 from __future__ import annotations
@@ -34,7 +36,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from scipy.special import erf as _erf
 
 _JITTER = 1e-6
@@ -315,7 +316,7 @@ class GP:
         yp = np.zeros((b,))
         mask = np.zeros((b,))
         Xp[:n], yp[:n], mask[:n] = X, y, 1.0
-        with enable_x64():
+        with jax.enable_x64(True):
             params = _init_params(self.kind, d)
             params["mean_const"] = jnp.asarray(float(y.mean()))
             params["log_tau"] = jnp.asarray(
@@ -342,7 +343,7 @@ class GP:
         instead of refactorizing per call."""
         assert self._state is not None, "fit() first"
         params, Xp, yp, mask = self._state
-        with enable_x64():
+        with jax.enable_x64(True):
             Xs = jnp.asarray(Xs, jnp.float64)
             if self._fac is not None:
                 return _posterior_chol(params, self._fac, Xp, yp, mask, Xs,
@@ -361,7 +362,7 @@ class GP:
         params, Xp, yp, mask = self._state
         n = int(np.asarray(mask).sum())
         b = Xp.shape[0]
-        with enable_x64():
+        with jax.enable_x64(True):
             if n >= b:
                 # Bucket overflow: repad to the next bucket and refactorize
                 # (O(n^3), but only at power-of-two boundaries -- amortized
@@ -400,7 +401,7 @@ class GP:
         Xp[:n], yp[:n], mask[:n] = X, y, 1.0
         other = GP(kind=self.kind, noisy=self.noisy, steps=self.steps,
                    fit_tol=self.fit_tol)
-        with enable_x64():
+        with jax.enable_x64(True):
             other._state = (params, jnp.asarray(Xp), jnp.asarray(yp),
                             jnp.asarray(mask))
         return other
@@ -445,7 +446,7 @@ class GPClassifier:
         if self._gp is None:
             return jnp.ones(len(Xs))
         mu, var = self._gp.posterior_device(Xs)
-        with enable_x64():
+        with jax.enable_x64(True):
             z = mu / jnp.sqrt(1.0 + var)
             return 0.5 * (1.0 + jax.scipy.special.erf(z / np.sqrt(2.0)))
 
@@ -568,7 +569,7 @@ class GPStack:
         ys = [np.asarray(yk, np.float64) for yk in ys]
         X, y, mask = _pad_runs(Xs, ys)
         L, _, d = X.shape
-        with enable_x64():
+        with jax.enable_x64(True):
             params = jax.tree.map(
                 lambda leaf: jnp.broadcast_to(leaf, (L, *leaf.shape)),
                 _init_params(self.kind, d))
@@ -598,7 +599,7 @@ class GPStack:
         returning (L, P) device arrays (the fused multi-run scoring path)."""
         assert self._state is not None, "fit() first"
         params, Xp, yp, mask = self._state
-        with enable_x64():
+        with jax.enable_x64(True):
             Xs = jnp.asarray(Xs, jnp.float64)
             return _posterior_stack(params, Xp, yp, mask, Xs, self.kind)
 
@@ -612,7 +613,7 @@ class GPStack:
         assert self._state is not None, "fit() first"
         params, Xp, yp, mask = self._state
         acq_fn = _acq_device_cached(acquisition, float(lam))
-        with enable_x64():
+        with jax.enable_x64(True):
             idx, rows = _score_stack(
                 params, Xp, yp, mask,
                 jnp.asarray(feats, jnp.float64), jnp.asarray(best, jnp.float64),
@@ -648,6 +649,6 @@ class GPClassifierStack:
         erf precision under scoped x64)."""
         assert self._stack is not None, "fit() first"
         mu, var = self._stack.posterior_device(Xs)
-        with enable_x64():
+        with jax.enable_x64(True):
             z = mu / jnp.sqrt(1.0 + var)
             return 0.5 * (1.0 + jax.scipy.special.erf(z / np.sqrt(2.0)))

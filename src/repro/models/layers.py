@@ -8,7 +8,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map as _shard_map
 
 from repro.configs.base import ModelConfig
 from repro.parallel import sharding
@@ -455,11 +454,11 @@ def embed(p, tokens):
         rows = jnp.where(inb[..., None], rows, 0)
         return jax.lax.psum(rows, "model")
 
-    out = _shard_map(
+    out = jax.shard_map(
         f, mesh=mesh,
         in_specs=(P("model", None), P(dp, None)),
         out_specs=P(dp, None, None),
-        check_rep=False,
+        check_vma=False,
     )(table, tokens)
     return sharding.act(out, "batch", "seq", "dmodel")
 
@@ -528,10 +527,10 @@ def softmax_xent(p_embed, x, labels, vocab_size: int):
         label_logit = jax.lax.psum(jnp.where(inb, ll, 0.0), "model")
         return lse - label_logit
 
-    per_tok = _shard_map(
+    per_tok = jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(dp, None, "model"), P(dp, None)),
         out_specs=P(dp, None),
-        check_rep=False,
+        check_vma=False,
     )(logits, labels)
     return jnp.mean(per_tok)

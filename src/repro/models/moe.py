@@ -82,7 +82,6 @@ def _moe_block_local(p, cfg: ModelConfig, x):
 
 def _moe_block_shardmap(p, cfg: ModelConfig, x, mesh):
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     B, S, D = x.shape
     E, k = cfg.num_experts, cfg.top_k
@@ -124,12 +123,12 @@ def _moe_block_shardmap(p, cfg: ModelConfig, x, mesh):
         out = jax.lax.psum(out.astype(jnp.bfloat16), "model")
         return out.reshape(Bl, S, D)
 
-    out = shard_map(
+    out = jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(None), P(None, None), P("model", None, None),
                   P("model", None, None), P(dp_axes, None, None)),
         out_specs=P(dp_axes, None, None),
-        check_rep=False,
+        check_vma=False,
     )(p["ln"], p["router"], p["expert_wi"], p["expert_wo"], x)
     out = out.astype(x.dtype)
     return sharding.act(out, "batch", "seq", "dmodel")

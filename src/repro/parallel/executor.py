@@ -25,17 +25,21 @@ Two implementations share one small interface (`submit`/`ready`/`run`/
                      executor can drop in behind the same four methods.
 
 Spawn, never fork: a forked child would inherit the parent's jax runtime
-and x64 globals (see `workers.py`, which asserts the invariant).
+and x64 globals (see `workers.py`, which asserts the invariant).  Workers are
+pinned to the host CPU: an accelerator belongs to one process, the learner,
+so the process executor only runs numpy-backend searches.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import multiprocessing as mp
+import os
 import queue as _queue
 from typing import Any
 
-from repro.core.config import ExecutorConfig
+from repro.core.config import EngineConfig, ExecutorConfig
 from repro.parallel import workers as _workers
 
 
@@ -108,6 +112,22 @@ def _chunk_spec(spec, n_workers: int, chunk_items: int) -> list:
             for i in range(0, n, chunk_items)]
 
 
+@contextlib.contextmanager
+def _cpu_only_env():
+    """Spawned children copy the parent's environment when they start, before
+    they import anything: `JAX_PLATFORMS=cpu` there keeps every jax backend
+    in them on the host CPU."""
+    old = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        yield
+    finally:
+        if old is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = old
+
+
 class ProcessExecutor(Executor):
     """Persistent spawn-started worker pool behind two mp queues.
 
@@ -138,11 +158,12 @@ class ProcessExecutor(Executor):
             return
         self._tq = self._ctx.Queue()
         self._rq = self._ctx.Queue()
-        for _ in range(self.n_workers):
-            p = self._ctx.Process(target=_workers.worker_main,
-                                  args=(self._tq, self._rq), daemon=True)
-            p.start()
-            self._procs.append(p)
+        with _cpu_only_env():
+            for _ in range(self.n_workers):
+                p = self._ctx.Process(target=_workers.worker_main,
+                                      args=(self._tq, self._rq), daemon=True)
+                p.start()
+                self._procs.append(p)
 
     def close(self) -> None:
         if not self._procs:
@@ -216,6 +237,7 @@ class ProcessExecutor(Executor):
     def submit(self, job_id, spec) -> None:
         if job_id in self._pending:
             raise ValueError(f"job id {job_id!r} already in flight")
+        _check_backend(spec.engine)
         self._ensure_started()
         chunks = _chunk_spec(spec, self.n_workers, self.chunk_items)
         self._pending[job_id] = {"n": len(chunks), "parts": {}}
@@ -253,6 +275,17 @@ class ProcessExecutor(Executor):
         self._pending[jid] = {"n": 1, "parts": {}, "raw": True}
         self._tq.put(("probe", jid, 0, None))
         return self._wait(jid)
+
+
+def _check_backend(engine) -> None:
+    """A CPU-pinned worker running a jax-backend search would be a hidden
+    CPU fallback for the accelerator path: refuse it."""
+    if (engine or EngineConfig()).resolve_backend() == "jax":
+        raise ValueError(
+            "the process executor runs numpy-backend searches only: its "
+            "workers are pinned to the host CPU, and the accelerator belongs "
+            "to the learner process (use ExecutorConfig(kind='inline') with "
+            "backend='jax')")
 
 
 def make_executor(cfg: ExecutorConfig | None = None) -> Executor:

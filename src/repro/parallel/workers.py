@@ -59,9 +59,19 @@ def _x64_enabled() -> bool:
     return bool(jax is not None and jax.config.jax_enable_x64)
 
 
+def _jax_platform() -> str | None:
+    """The backend jax runs on here: the initialized default once jax is
+    loaded, else the platform `JAX_PLATFORMS` pins it to."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return os.environ.get("JAX_PLATFORMS")
+    return jax.default_backend()
+
+
 def _probe_report(inherited_jax: list[str]) -> dict:
     """Snapshot of the invariants the no-jax regression test pins."""
     return {
+        "jax_platform": _jax_platform(),
         "inherited_jax": list(inherited_jax),
         "jax_modules": _jax_modules(),
         "engine_modules": [m for m in _JAX_ENGINE_MODULES if m in sys.modules],
@@ -88,7 +98,7 @@ def _run_search(spec, inherited_jax: list[str]) -> list:
         if _x64_enabled():
             raise RuntimeError(
                 "a worker search flipped the process-global jax_enable_x64 "
-                "flag; x64 must stay scoped (repro.core.gp.enable_x64)")
+                "flag; x64 must stay scoped (jax.enable_x64(True))")
     return entries
 
 

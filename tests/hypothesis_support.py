@@ -1,23 +1,15 @@
-"""The dedicated guard for the hypothesis-based property modules, plus shared
-strategies for the config API.
+"""Shared hypothesis strategies for the config API property tests.
 
-Import this FIRST in every property-test module:
+Import it from the property-test modules:
 
     from hypothesis_support import given, settings, st
 
-The container CI image does not ship hypothesis (only the GitHub CI install
-does, via requirements.txt); `pytest.importorskip` at import time raises
-pytest's Skipped, so any module importing this one is skipped whole -- tier-1
-stays green wherever hypothesis is absent, without each module repeating the
-guard dance.  Not named test_*, so pytest never collects it directly.
+Not named test_*, so pytest never collects it directly.
 """
 
-import pytest
+from hypothesis import given, settings, strategies as st
 
-hypothesis = pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st  # noqa: E402
-
-from repro.core import (ACQUISITIONS, BACKENDS, PALLAS_MODES,  # noqa: E402
+from repro.core import (ACQUISITIONS, BACKENDS, PALLAS_MODES,
                         PRUNE_MODES, STRATEGIES, SURROGATES)
 
 # --- CodesignConfig strategies ----------------------------------------------------

@@ -9,6 +9,7 @@ path is checked against the looser bar it is specified to meet.
 
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 
@@ -107,6 +108,31 @@ def test_pallas_interpret_mode_matches_jnp():
         )
 
 
+@pytest.mark.parametrize("rows", [48, 1024, 2600])
+def test_edp_reduce_kernel_matches_reference(rows):
+    """The lane-major kernel (through the interpreter) against its `jnp`
+    reference, across one partial lane block, one full grid block, and a
+    padded multi-block grid."""
+    import jax.numpy as jnp
+
+    from repro.kernels.edp_reduce import edp_reduce, reduce_edp_terms
+
+    rng = np.random.default_rng(rows)
+    ops = (rng.integers(1, 9, (rows, 2, 6)),
+           rng.integers(0, 2, (rows, 2, 3, 6)),
+           rng.integers(1, 5000, (rows, 2, 3)),
+           rng.integers(1, 169, (rows, 6)),
+           rng.uniform(0.5, 200.0, (rows, 7)))
+    with jax.enable_x64(True):
+        ops = [jnp.asarray(o, jnp.float64) for o in ops]
+        want = reduce_edp_terms(*ops)
+        got = edp_reduce(*ops, interpret=True)
+        for g, w in zip(got, want):
+            assert g.shape == w.shape
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-12)
+
+
 def test_valid_batch_and_scalar_oracle():
     layer = PAPER_WORKLOADS["MLP-K2"]
     hw = eyeriss_168()
@@ -168,12 +194,11 @@ def test_acquisition_device_twins_match_host():
     from repro.core.acquisition import make_acquisition, make_acquisition_device
 
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     rng = np.random.default_rng(0)
     mu = rng.normal(size=50)
     var = rng.uniform(1e-8, 2.0, size=50)
-    with enable_x64():  # the real device path feeds f64 posterior arrays
+    with jax.enable_x64(True):  # the real device path feeds f64 posterior arrays
         mu_d, var_d = jnp.asarray(mu), jnp.asarray(var)
     for name in ("ei", "lcb"):
         host = make_acquisition(name, lam=1.3)(mu, var, 0.4)

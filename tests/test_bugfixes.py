@@ -89,10 +89,8 @@ def test_deterministic_gp_log_tau_stays_pinned():
     gp = GP(kind="linear", noisy=False).fit(X, y)
     assert float(gp.params["log_tau"]) == -6.0
     # re-pinning after the fact changes nothing about the posterior
-    from jax.experimental import enable_x64
-
     mu_before, var_before = gp.posterior(X)
-    with enable_x64():  # match the stored f64 dtype, as GP.fit does
+    with jax.enable_x64(True):  # match the stored f64 dtype, as GP.fit does
         gp.params["log_tau"] = jnp.asarray(-6.0)
     mu_after, var_after = gp.posterior(X)
     np.testing.assert_array_equal(mu_before, mu_after)

@@ -20,8 +20,6 @@ def test_cost_analysis_counts_scan_body_once():
     x = jax.ShapeDtypeStruct((64, 128), jnp.float32)
     w = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     ca = jax.jit(f_scan).lower(x, w).compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax < 0.5 returned a per-device list
-        ca = ca[0]
     one_iter = 2 * 64 * 128 * 128
     assert abs(ca["flops"] - one_iter) / one_iter < 0.1  # body counted ONCE
 

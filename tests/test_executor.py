@@ -54,11 +54,13 @@ def test_executor_config_validation():
 def test_executor_config_json_roundtrip():
     """The executor section rides the existing config JSON surfaces: dicts
     coerce to ExecutorConfig on the way in, round-trip equality holds."""
-    eng = EngineConfig(executor=ExecutorConfig(kind="process", n_workers=2))
+    eng = EngineConfig(backend="numpy",
+                       executor=ExecutorConfig(kind="process", n_workers=2))
     cfg = CodesignConfig(engine=eng)
     assert CodesignConfig.from_json(cfg.to_json()) == cfg
     # plain-dict executor section (the JSON queue path) coerces + validates
-    assert EngineConfig(executor={"kind": "process"}).executor == \
+    assert EngineConfig(backend="numpy",
+                        executor={"kind": "process"}).executor == \
         ExecutorConfig(kind="process")
     with pytest.raises(ValueError, match="executor"):
         EngineConfig(executor={"kind": "process", "bogus": 1})
@@ -135,6 +137,43 @@ def test_worker_error_propagates_with_traceback():
         assert ex.run(_tiny_spec(2)) == InlineExecutor().run(_tiny_spec(2))
     finally:
         ex.close()
+
+
+def test_process_executor_refuses_jax_backend(monkeypatch):
+    """Workers are pinned to the host CPU, so a jax-backend search there
+    would be a hidden CPU fallback for the accelerator path: the config and
+    the executor both refuse it."""
+    with pytest.raises(ValueError, match="process"):
+        EngineConfig(backend="jax", executor=ExecutorConfig(kind="process"))
+    monkeypatch.setenv("REPRO_BACKEND", "jax")  # resolved, not just explicit
+    with pytest.raises(ValueError, match="process"):
+        EngineConfig(executor={"kind": "process"})
+    monkeypatch.delenv("REPRO_BACKEND")
+    spec = dataclasses.replace(_tiny_spec(1), engine=EngineConfig(backend="jax"))
+    ex = ProcessExecutor(n_workers=1)
+    try:
+        with pytest.raises(ValueError, match="numpy-backend"):
+            ex.submit("j", spec)
+        assert not ex._procs  # refused before any worker started
+    finally:
+        ex.close()
+
+
+def test_spawned_worker_runs_jax_on_cpu(monkeypatch):
+    """Workers never reach for the accelerator, even when the parent leaves
+    the jax platform unset: a fresh worker boots pinned to the CPU, and after
+    a search its initialized jax backend is the CPU."""
+    import os
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    ex = ProcessExecutor(n_workers=1)
+    try:
+        assert ex.probe()["jax_platform"] == "cpu"
+        ex.run(_tiny_spec(1))
+        assert ex.probe()["jax_platform"] == "cpu"
+    finally:
+        ex.close()
+    assert "JAX_PLATFORMS" not in os.environ  # the parent's env is restored
 
 
 # --- spawn hygiene (the no-jax satellite) -----------------------------------------

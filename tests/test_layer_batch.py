@@ -14,6 +14,7 @@ NLL, the batched hardware-pool protocol, and the end-to-end `gp_refit_every`
 threading (which also exercises the multi-cohort refit schedule).
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -117,7 +118,7 @@ def test_forward_device_stacked_matches_per_layer():
 
 def test_forward_device_stacked_interpret_mode():
     """The Pallas-kernel path handles the stacked row count (L*bucket is not
-    a power of two) by shrinking its block size."""
+    a multiple of the 128-row lane block) by padding to whole blocks."""
     hw = eyeriss_168()
     layers = MODEL_LAYERS["resnet"][:3]
     rng = np.random.default_rng(1)
@@ -205,7 +206,6 @@ def test_gp_classifier_stack_matches_individual():
 
 def test_lowrank_nll_matches_cholesky_nll():
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core.gp import _init_params, _nll, _nll_linear_lowrank
 
@@ -213,7 +213,7 @@ def test_lowrank_nll_matches_cholesky_nll():
     n, npad, d = 21, 32, 7
     X = np.zeros((npad, d)); y = np.zeros(npad); mask = np.zeros(npad)
     X[:n] = rng.normal(size=(n, d)); y[:n] = rng.normal(size=n); mask[:n] = 1.0
-    with enable_x64():
+    with jax.enable_x64(True):
         params = dict(_init_params("linear", d),
                       mean_const=jnp.asarray(0.4), log_tau=jnp.asarray(-6.0),
                       log_w=jnp.asarray(rng.normal(size=d) * 0.3),

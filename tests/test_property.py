@@ -1,6 +1,4 @@
-"""Property-based tests (hypothesis) over the system's invariants.
-Module-guarded through `hypothesis_support` (skipped whole where hypothesis
-is not installed)."""
+"""Property-based tests (hypothesis) over the system's invariants."""
 
 import numpy as np
 

@@ -1,7 +1,6 @@
 """Property-based tests of the typed config API (hypothesis): JSON round-trip
 over randomized valid configs, and loud `ValueError` rejection of invalid
-enumerated strings and `spec_k`/`elite_k` bounds.  Module-guarded through
-`hypothesis_support` (skipped whole where hypothesis is not installed)."""
+enumerated strings and `spec_k`/`elite_k` bounds."""
 
 import dataclasses
 import json

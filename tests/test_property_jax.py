@@ -1,9 +1,5 @@
-"""Property-based parity of the JAX engine vs the NumPy engine (hypothesis).
-
-Lives in its own module so the module-level `importorskip` only skips the
-property test where hypothesis is unavailable -- the deterministic parity
-suite in `test_batch_jax.py` always runs.
-"""
+"""Property-based parity of the JAX engine vs the NumPy engine (hypothesis);
+the deterministic parity suite is `test_batch_jax.py`."""
 
 import numpy as np
 

@@ -52,6 +52,11 @@ MIXED_REQUESTS = [  # mixed workloads x strategies x seeds
 ]
 
 
+def _on_numpy(config):
+    return dataclasses.replace(config, engine=dataclasses.replace(
+        config.engine, backend="numpy"))
+
+
 def _standalone(model, config):
     return CodesignEngine(config).run(MODEL_LAYERS[model])
 
@@ -250,12 +255,14 @@ def service_pool():
 def test_process_executor_service_matches_standalone(service_pool):
     """The mixed batch through a process-executor service -- overlapped
     ticks: sessions park while their fused dispatches are in flight, step
-    as results land -- is bit-identical to standalone runs."""
-    refs = [_standalone(m, c) for m, c in MIXED_REQUESTS]
-    svc = CodesignService(ServiceConfig(max_slots=len(MIXED_REQUESTS)),
+    as results land -- is bit-identical to standalone runs.  Process
+    workers run numpy-backend searches only."""
+    reqs = [(m, _on_numpy(c)) for m, c in MIXED_REQUESTS]
+    refs = [_standalone(m, c) for m, c in reqs]
+    svc = CodesignService(ServiceConfig(max_slots=len(reqs)),
                           executor=service_pool)
     rids = [svc.submit(ServiceRequest(layers=tuple(MODEL_LAYERS[m]), config=c))
-            for m, c in MIXED_REQUESTS]
+            for m, c in reqs]
     responses = svc.run()
     for rid, ref in zip(rids, refs):
         _assert_parity(responses[rid].result, ref, where=rid)
@@ -267,9 +274,9 @@ def test_mixed_fuse_groups_stagger_under_executor(service_pool):
     requests with different sw_cfg must land in separate fuse groups --
     every submitted spec carries exactly one config, and both configs'
     groups are dispatched -- and still match standalone parity."""
-    cfg_a = svc_config(0, n_hw=3)
+    cfg_a = svc_config(0, n_hw=3, backend="numpy")
     cfg_b = dataclasses.replace(
-        svc_config(1, n_hw=4),
+        svc_config(1, n_hw=4, backend="numpy"),
         sw=SWSearchConfig(n_trials=10, n_warmup=4, pool_size=14))
     reqs = [("dqn", cfg_a), ("mlp", cfg_b), ("dqn", cfg_b),
             ("mlp", dataclasses.replace(cfg_a, seed=9))]

@@ -123,8 +123,6 @@ def test_straggler_monitor_flags_outlier():
     assert mon.flagged == 1
 
 
-@pytest.mark.skipif(not hasattr(jax.sharding, "AxisType"),
-                    reason="jax.sharding.AxisType requires jax >= 0.5")
 def test_sharding_filter_spec():
     from jax.sharding import PartitionSpec as P
     from repro.parallel.sharding import _filter_spec
@@ -134,8 +132,6 @@ def test_sharding_filter_spec():
     assert spec == P(("data",), None, None)
 
 
-@pytest.mark.skipif(not hasattr(jax.sharding, "AxisType"),
-                    reason="jax.sharding.AxisType requires jax >= 0.5")
 def test_param_spec_roles():
     from repro.parallel.sharding import AxisRules, param_spec
     mesh = jax.make_mesh((1, 1), ("data", "model"),

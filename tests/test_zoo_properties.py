@@ -1,5 +1,5 @@
-"""Hypothesis properties for the zoo generator and the sampler divisor guard
-(skipped whole where hypothesis is absent -- see hypothesis_support)."""
+"""Hypothesis properties for the zoo generator and the sampler divisor
+guard."""
 
 from hypothesis_support import given, settings, st
 
