@@ -12,7 +12,8 @@ from repro.core.config import (ACQUISITIONS, BACKENDS, EXECUTOR_KINDS,
                                ExecutorConfig, HWSearchConfig, SearchConfig,
                                ServiceConfig, SWSearchConfig,
                                config_from_legacy_kwargs)
-from repro.core.cache import LRUCache, SlotCache, counters_snapshot
+from repro.core.cache import LRUCache, SlotCache
+from repro.core.trace import counters_snapshot
 from repro.core.gp import GP, GPClassifier, GPClassifierStack, GPStack
 from repro.core.acquisition import expected_improvement, lcb, make_acquisition
 from repro.core.bo import (BOLoop, BOResult, FanoutSearchSpec, bo_maximize,

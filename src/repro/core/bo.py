@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.core.acquisition import make_acquisition, make_acquisition_device
 from repro.core.config import BACKENDS, SearchConfig, SWSearchConfig
 from repro.core.gp import (GP, GPClassifier, GPClassifierStack, GPStack,
@@ -189,6 +190,10 @@ class BOLoop:
     frozen window are copied, and the surrogate/classifier are *refit* from
     the recorded fit boundary on restore (model fits are deterministic given
     their data, so the restored loop continues bit-identically).
+
+    `gp_span` names the span its surrogate's fits and pool scorings run
+    under: `codesign.gp` for an inner search, `codesign.outer_gp` for the
+    hardware loop of a `SearchSession` (`repro.core.trace`).
     """
 
     def __init__(
@@ -203,10 +208,12 @@ class BOLoop:
         callback: Callable[[int, BOResult], None] | None = None,
         prior: dict | None = None,
         prior_mean_fn: Callable | None = None,
+        gp_span: str = "codesign.gp",
         **overrides,
     ):
         cfg = _resolve_search_config(config, overrides)
         self.space = space
+        self.gp_span = gp_span
         self.cfg = cfg
         self.noisy = noisy
         self.seed = seed
@@ -374,7 +381,9 @@ class BOLoop:
         if np.isfinite(v):
             if self._prior_mean_fn is not None:
                 v = v - self._m_feas[-1]  # the GP holds residuals y - m(x)
-            self._model.append_observation(np.asarray(feat_row, np.float64), v)
+            with trace.span(self.gp_span):
+                self._model.append_observation(
+                    np.asarray(feat_row, np.float64), v)
 
     def _update_elites(self, pool, utility, i_best) -> None:
         elite_k, observed = self.elite_k, self._observed
@@ -414,6 +423,10 @@ class BOLoop:
         if not (len(self._y_feas) >= 2
                 and (self._model is None or t % self.gp_refit_every == 0)):
             return
+        with trace.span(self.gp_span):
+            self._refit(t, surrogate)
+
+    def _refit(self, t: int, surrogate: str) -> None:
         Xf = np.stack(self._X_feas)
         yf = np.asarray(self._y_feas)
         if self._prior_mean_fn is not None:
@@ -487,7 +500,7 @@ class BOLoop:
             feats_dev = self.space.features_batch_device(pool)
             # The posterior is f64: every op on it stays in scoped x64, or
             # jax truncates it to f32 outside the scope.
-            with jax.enable_x64(True):
+            with trace.span(self.gp_span), jax.enable_x64(True):
                 mu, var = self._model.posterior_device(feats_dev)
                 utility = self._acq_dev(mu, var, self.result.best_value)
                 if self._classifier is not None:
@@ -537,18 +550,20 @@ class BOLoop:
             feats = np.stack([self.space.features(p) for p in pool])
         if self._can_freeze and not frozen and isinstance(pool, list):
             self._window_pool, self._window_feats = pool, feats
-        mu, var = self._model.posterior(feats)
-        if self._prior_mean_fn is not None:
-            # The surrogate holds residuals y - m(x); put m back before the
-            # acquisition so utilities compare against the true incumbent.
-            mu = apply_prior_mean(mu, self._prior_mean_fn(pool))
-        utility = self._acq(mu, var, self.result.best_value)
-        if self._classifier is not None:
-            # prob_feasible returns a host array; the asarray keeps the
-            # boundary explicit so the acquisition math never silently
-            # promotes to device arrays.
-            utility = utility * np.asarray(
-                self._classifier.prob_feasible(feats))
+        with trace.span(self.gp_span):
+            mu, var = self._model.posterior(feats)
+            if self._prior_mean_fn is not None:
+                # The surrogate holds residuals y - m(x); put m back before
+                # the acquisition so utilities compare against the true
+                # incumbent.
+                mu = apply_prior_mean(mu, self._prior_mean_fn(pool))
+            utility = self._acq(mu, var, self.result.best_value)
+            if self._classifier is not None:
+                # prob_feasible returns a host array; the asarray keeps the
+                # boundary explicit so the acquisition math never silently
+                # promotes to device arrays.
+                utility = utility * np.asarray(
+                    self._classifier.prob_feasible(feats))
         if frozen:
             # Already-consumed candidates leave the frozen window pool.
             utility = np.where([p in self._observed for p in pool],
@@ -594,10 +609,10 @@ class BOLoop:
         if plan["device"]:
             # Host copies at the boundary: the f64 utility never meets a jnp
             # op outside scoped x64.
-            utility = np.asarray(utility)
+            utility = trace.fetch(utility)
             _prefetch_topk(self.space, pool, utility)
             i_best = int(np.argmax(utility))
-            feat_row = np.asarray(plan["feats_dev"], dtype=np.float64)[i_best]
+            feat_row = trace.fetch(plan["feats_dev"], np.float64)[i_best]
             self._observe(pool[i_best], feats=feat_row)
             self._rank1_update(feat_row)
         else:
@@ -795,7 +810,19 @@ def bo_maximize_many(
 
     `callback`, when given, receives `(trial_index, results_list)` once per
     lockstep round (not per run; on the sequential fallback it fires per
-    advancing run, with empty placeholders for runs not yet started)."""
+    advancing run, with empty placeholders for runs not yet started).
+
+    Traced as one `codesign.inner` span, its pool sampling loops as
+    `codesign.sample` (`repro.core.trace`)."""
+    with trace.span("codesign.inner"):
+        return _bo_maximize_many(
+            spaces, config, noisy=noisy, seed=seed,
+            gp_refit_every=gp_refit_every, callback=callback, backend=backend,
+            **overrides)
+
+
+def _bo_maximize_many(spaces, config, *, noisy, seed, gp_refit_every,
+                      callback, backend, **overrides) -> list[BOResult]:
     cfg = _resolve_search_config(config, overrides)
     spaces = list(spaces)
     L = len(spaces)
@@ -807,9 +834,10 @@ def bo_maximize_many(
                          f"for {L} spaces")
     if backend is not None:
         with _backend_override(spaces, backend):
-            return bo_maximize_many(
+            return _bo_maximize_many(
                 spaces, cfg, noisy=noisy, seed=seeds,
                 gp_refit_every=gp_refit_every, callback=callback,
+                backend=None,
             )
     n_trials, n_warmup, pool_size = cfg.n_trials, cfg.n_warmup, cfg.pool_size
     acquisition, lam, surrogate = cfg.acquisition, cfg.lam, cfg.surrogate
@@ -892,13 +920,11 @@ def bo_maximize_many(
     # --- warmup: one stacked evaluation over all runs' warmup pools -----------
     n_warm = min(n_warmup, n_trials)
     if n_warm:
-        pools = []
+        with trace.span("codesign.sample"):
+            pools = [spaces[k].sample_pool(rngs[k], n_warm) for k in range(L)]
         for k in range(L):
-            p = spaces[k].sample_pool(rngs[k], n_warm)
-            if p is None:
+            if pools[k] is None:
                 kill(k)
-                p = None
-            pools.append(p)
         live = [k for k in range(L) if alive[k]]
         if live:
             if stack is not None:
@@ -945,9 +971,13 @@ def bo_maximize_many(
 
         # Runs without a surrogate yet keep sampling (scalar, like the
         # sequential path: one candidate, scalar features + evaluation).
-        for k in range(L):
-            if alive[k] and cohort_of[k] is None:
-                p = spaces[k].sample_pool(rngs[k], 1)
+        # Each run draws from its own stream, so drawing all candidates
+        # before observing any keeps every run's trajectory.
+        early = [k for k in range(L) if alive[k] and cohort_of[k] is None]
+        if early:
+            with trace.span("codesign.sample"):
+                drawn = [spaces[k].sample_pool(rngs[k], 1) for k in early]
+            for k, p in zip(early, drawn):
                 if p is None:
                     kill(k)
                 else:
@@ -956,8 +986,10 @@ def bo_maximize_many(
         scoring = [k for k in range(L) if alive[k] and cohort_of[k] is not None]
         if scoring:
             pools = [None] * L
+            with trace.span("codesign.sample"):
+                for k in scoring:
+                    pools[k] = spaces[k].sample_pool(rngs[k], pool_size)
             for k in scoring:
-                pools[k] = spaces[k].sample_pool(rngs[k], pool_size)
                 if pools[k] is None:
                     kill(k)
             scoring = [k for k in scoring if alive[k]]
@@ -988,7 +1020,7 @@ def bo_maximize_many(
                     # sequential path's Python-float best) or EI loses
                     # precision.
                     with jax.enable_x64(True):
-                        sub = feats_dev[jnp.asarray(runs)]
+                        sub = feats_dev[trace.to_device(runs)]
                     if cohort.clf is None:
                         # Hot case (the inner software searches sample
                         # input-valid pools, so no classifier ever fits):
@@ -997,29 +1029,30 @@ def bo_maximize_many(
                         idx, rows = cohort.model.score_device(
                             sub, best, acquisition, lam)
                     else:
-                        with jax.enable_x64(True):
+                        with trace.span("codesign.gp"), jax.enable_x64(True):
                             mu, var = cohort.model.posterior_device(sub)
-                            util = acq_dev(mu, var, jnp.asarray(best))
-                            pos = jnp.asarray(
+                            util = acq_dev(mu, var, trace.to_device(best))
+                            pos = trace.to_device(
                                 [runs.index(k) for k in cohort.clf_runs])
                             probs = cohort.clf.prob_feasible_device(
-                                feats_dev[jnp.asarray(cohort.clf_runs)])
+                                feats_dev[trace.to_device(cohort.clf_runs)])
                             util = util.at[pos].multiply(probs)
-                            idx = np.asarray(jnp.argmax(util, axis=1))
-                            rows = np.asarray(
+                            idx = trace.fetch(jnp.argmax(util, axis=1))
+                            rows = trace.fetch(
                                 jnp.take_along_axis(
-                                    sub, jnp.asarray(idx)[:, None, None],
+                                    sub, trace.to_device(idx)[:, None, None],
                                     axis=1)[:, 0, :],
-                                dtype=np.float64)
+                                np.float64)
                 else:
                     sub = feats[np.asarray(runs)]
-                    mu, var = cohort.model.posterior(sub)
-                    util = acq(mu, var, best)
-                    if cohort.clf is not None:
-                        pos = [runs.index(k) for k in cohort.clf_runs]
-                        util[pos] = util[pos] * np.asarray(
-                            cohort.clf.prob_feasible(
-                                feats[np.asarray(cohort.clf_runs)]))
+                    with trace.span("codesign.gp"):
+                        mu, var = cohort.model.posterior(sub)
+                        util = acq(mu, var, best)
+                        if cohort.clf is not None:
+                            pos = [runs.index(k) for k in cohort.clf_runs]
+                            util[pos] = util[pos] * np.asarray(
+                                cohort.clf.prob_feasible(
+                                    feats[np.asarray(cohort.clf_runs)]))
                     idx = np.argmax(util, axis=1)
                     rows = sub[np.arange(len(runs)), idx]
                 for r, k in enumerate(runs):

@@ -14,9 +14,11 @@ traffic:
                 `HardwareSpace.features_batch` / `SoftwareSpace`'s forward
                 and feature caches: a tiny LRU over `is`-compared pool
                 objects (the historical one-slot tuples, generalized and
-                counted).  Traffic tallies into the module-level `COUNTERS`
-                so per-probe spaces -- created and dropped inside one outer
-                trial -- still aggregate into the run's stats.
+                counted).  Traffic tallies into the process-wide
+                `repro.core.trace.COUNTERS` registry (`<name>_hits`,
+                `<name>_misses`), so per-probe spaces -- created and dropped
+                inside one outer trial -- still aggregate into the run's
+                stats.
 
 Eviction never changes search results when `prune="off"`: cache keys are
 content-addressed and inner-search seeds are content-derived
@@ -33,16 +35,7 @@ from __future__ import annotations
 import collections
 from typing import Any, Iterator, MutableMapping
 
-# Global hit/miss tallies for the short-lived SlotCaches, keyed
-# "<name>_hits" / "<name>_misses".  Snapshot + diff around a run to get
-# per-run numbers (see `counters_snapshot`).
-COUNTERS: collections.Counter = collections.Counter()
-
-
-def counters_snapshot() -> dict[str, int]:
-    """Copy of the global SlotCache tallies (diff two snapshots for a
-    per-run reading)."""
-    return dict(COUNTERS)
+from repro.core.trace import COUNTERS
 
 
 # `LRUCache._primed` sentinel: "no membership probe pending".  A distinct
@@ -138,7 +131,7 @@ class SlotCache:
     hits; equal-valued but distinct pools do not (identity is the memo's
     correctness contract -- pools are never mutated in place).
 
-    `name` routes hit/miss tallies into the module `COUNTERS`
+    `name` routes hit/miss tallies into `repro.core.trace.COUNTERS`
     ("<name>_hits" / "<name>_misses") so short-lived space instances still
     aggregate into run-level stats.
     """

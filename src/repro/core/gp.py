@@ -38,6 +38,8 @@ import jax.numpy as jnp
 import numpy as np
 from scipy.special import erf as _erf
 
+from repro.core import trace
+
 _JITTER = 1e-6
 _PAD_NOISE = 1e6  # effective infinite noise on padded rows -> zero influence
 # Stacked linear-kernel fits switch to the O(n d^2) Woodbury NLL above this
@@ -316,25 +318,27 @@ class GP:
         yp = np.zeros((b,))
         mask = np.zeros((b,))
         Xp[:n], yp[:n], mask[:n] = X, y, 1.0
+        to_device = trace.to_device
         with jax.enable_x64(True):
             params = _init_params(self.kind, d)
-            params["mean_const"] = jnp.asarray(float(y.mean()))
-            params["log_tau"] = jnp.asarray(
+            params["mean_const"] = to_device(float(y.mean()))
+            params["log_tau"] = to_device(
                 np.log(max(y.std(), 1e-3) * 0.1) if self.noisy else -6.0)
             # With noisy=False the pinned log_tau is frozen *during* the fit
             # (zeroed gradient), so the remaining hyperparameters are trained
             # against the true fixed noise level -- no post-fit re-pin needed.
-            params = _fit(params, jnp.asarray(Xp), jnp.asarray(yp),
-                          jnp.asarray(mask), self.kind, self.steps,
+            params = _fit(params, to_device(Xp), to_device(yp),
+                          to_device(mask), self.kind, self.steps,
                           train_tau=self.noisy, tol=self.fit_tol)
-            self._state = (params, jnp.asarray(Xp), jnp.asarray(yp),
-                           jnp.asarray(mask))
+            trace.dispatched()
+            self._state = (params, to_device(Xp), to_device(yp),
+                           to_device(mask))
         self._fac = None  # a full refit invalidates any incremental factor
         return self
 
     def posterior(self, Xs: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
         mu, var = self.posterior_device(Xs)
-        return np.asarray(mu), np.asarray(var)
+        return trace.fetch(mu), trace.fetch(var)
 
     def posterior_device(self, Xs) -> tuple[jax.Array, jax.Array]:
         """Posterior as device arrays -- lets the batched-engine acquisition
@@ -343,8 +347,9 @@ class GP:
         instead of refactorizing per call."""
         assert self._state is not None, "fit() first"
         params, Xp, yp, mask = self._state
+        trace.dispatched()
         with jax.enable_x64(True):
-            Xs = jnp.asarray(Xs, jnp.float64)
+            Xs = trace.to_device(Xs, jnp.float64)
             if self._fac is not None:
                 return _posterior_chol(params, self._fac, Xp, yp, mask, Xs,
                                        self.kind)
@@ -360,7 +365,7 @@ class GP:
         scratch) to <= 1e-8."""
         assert self._state is not None, "fit() first"
         params, Xp, yp, mask = self._state
-        n = int(np.asarray(mask).sum())
+        n = int(trace.fetch(mask).sum())
         b = Xp.shape[0]
         with jax.enable_x64(True):
             if n >= b:
@@ -371,17 +376,20 @@ class GP:
                 Xp2 = np.zeros((b2, Xp.shape[1]))
                 yp2 = np.zeros((b2,))
                 mask2 = np.zeros((b2,))
-                Xp2[:n] = np.asarray(Xp)[:n]
-                yp2[:n] = np.asarray(yp)[:n]
+                Xp2[:n] = trace.fetch(Xp)[:n]
+                yp2[:n] = trace.fetch(yp)[:n]
                 mask2[:n] = 1.0
-                Xp, yp, mask = (jnp.asarray(Xp2), jnp.asarray(yp2),
-                                jnp.asarray(mask2))
+                Xp, yp, mask = (trace.to_device(Xp2), trace.to_device(yp2),
+                                trace.to_device(mask2))
                 self._fac = None
             if self._fac is None:
                 self._fac = _chol_factor(params, Xp, mask, self.kind)
+                trace.dispatched()
             self._fac, Xp, yp, mask = _append_row(
                 params, self._fac, Xp, yp, mask,
-                jnp.asarray(np.asarray(x, np.float64)), float(y), self.kind)
+                trace.to_device(np.asarray(x, np.float64)), float(y),
+                self.kind)
+            trace.dispatched()
         self._state = (params, Xp, yp, mask)
         return self
 
@@ -564,27 +572,36 @@ class GPStack:
     _state: tuple | None = None
 
     def fit(self, Xs, ys) -> "GPStack":
-        """Fit from per-run datasets: Xs[k] is (n_k, d), ys[k] is (n_k,)."""
+        """Fit from per-run datasets: Xs[k] is (n_k, d), ys[k] is (n_k,).
+        Traced as `codesign.gp`."""
+        with trace.span("codesign.gp"):
+            return self._fit_runs(Xs, ys)
+
+    def _fit_runs(self, Xs, ys) -> "GPStack":
         Xs = [np.asarray(Xk, np.float64) for Xk in Xs]
         ys = [np.asarray(yk, np.float64) for yk in ys]
         X, y, mask = _pad_runs(Xs, ys)
-        L, _, d = X.shape
+        L, b, d = X.shape
+        trace.COUNTERS["gp.rows"] += sum(len(yk) for yk in ys)
+        trace.COUNTERS["gp.slots"] += L * b
+        to_device = trace.to_device
         with jax.enable_x64(True):
             params = jax.tree.map(
                 lambda leaf: jnp.broadcast_to(leaf, (L, *leaf.shape)),
                 _init_params(self.kind, d))
             params = dict(
                 params,
-                mean_const=jnp.asarray([float(yk.mean()) for yk in ys]),
-                log_tau=jnp.asarray(
+                mean_const=to_device([float(yk.mean()) for yk in ys]),
+                log_tau=to_device(
                     [np.log(max(yk.std(), 1e-3) * 0.1) for yk in ys]
                     if self.noisy else [-6.0] * L),
             )
-            params = _fit_stack(params, jnp.asarray(X), jnp.asarray(y),
-                                jnp.asarray(mask), self.kind, self.steps,
+            params = _fit_stack(params, to_device(X), to_device(y),
+                                to_device(mask), self.kind, self.steps,
                                 self.noisy)
-            self._state = (params, jnp.asarray(X), jnp.asarray(y),
-                           jnp.asarray(mask))
+            trace.dispatched()
+            self._state = (params, to_device(X), to_device(y),
+                           to_device(mask))
         return self
 
     def __len__(self) -> int:
@@ -592,15 +609,16 @@ class GPStack:
 
     def posterior(self, Xs) -> tuple[np.ndarray, np.ndarray]:
         mu, var = self.posterior_device(Xs)
-        return np.asarray(mu), np.asarray(var)
+        return trace.fetch(mu), trace.fetch(var)
 
     def posterior_device(self, Xs) -> tuple[jax.Array, jax.Array]:
         """Stacked posterior: Xs is (L, P, d) -- one candidate pool per run --
         returning (L, P) device arrays (the fused multi-run scoring path)."""
         assert self._state is not None, "fit() first"
         params, Xp, yp, mask = self._state
+        trace.dispatched()
         with jax.enable_x64(True):
-            Xs = jnp.asarray(Xs, jnp.float64)
+            Xs = trace.to_device(Xs, jnp.float64)
             return _posterior_stack(params, Xp, yp, mask, Xs, self.kind)
 
     def score_device(
@@ -609,16 +627,19 @@ class GPStack:
         """One-dispatch pool scoring for the multi-run BO trial: stacked
         posterior, acquisition (vs per-run incumbents `best`, shape (L, 1)),
         per-run argmax, and the winners' feature rows -- only the (L,) indices
-        and (L, d) rows return to the host."""
+        and (L, d) rows return to the host.  Traced as `codesign.gp`."""
         assert self._state is not None, "fit() first"
         params, Xp, yp, mask = self._state
         acq_fn = _acq_device_cached(acquisition, float(lam))
-        with jax.enable_x64(True):
-            idx, rows = _score_stack(
-                params, Xp, yp, mask,
-                jnp.asarray(feats, jnp.float64), jnp.asarray(best, jnp.float64),
-                self.kind, acq_fn)
-        return np.asarray(idx), np.asarray(rows, dtype=np.float64)
+        with trace.span("codesign.gp"):
+            with jax.enable_x64(True):
+                idx, rows = _score_stack(
+                    params, Xp, yp, mask,
+                    trace.to_device(feats, jnp.float64),
+                    trace.to_device(best, jnp.float64),
+                    self.kind, acq_fn)
+            trace.dispatched()
+            return trace.fetch(idx), trace.fetch(rows, np.float64)
 
 
 @dataclasses.dataclass
@@ -631,8 +652,11 @@ class GPClassifierStack:
     _stack: GPStack | None = None
 
     def fit(self, Xs, feas) -> "GPClassifierStack":
-        ys = [np.where(np.asarray(f), 1.0, -1.0) for f in feas]
-        self._stack = GPStack(kind="se", noisy=True, steps=self.steps).fit(Xs, ys)
+        """Traced as `codesign.gp`."""
+        with trace.span("codesign.gp"):
+            ys = [np.where(np.asarray(f), 1.0, -1.0) for f in feas]
+            self._stack = GPStack(kind="se", noisy=True,
+                                  steps=self.steps).fit(Xs, ys)
         return self
 
     def prob_feasible(self, Xs) -> np.ndarray:

@@ -50,10 +50,11 @@ from typing import Callable, Sequence
 
 import numpy as np
 
+from repro.core import trace
 from repro.core.bo import (BOLoop, BOResult, FanoutSearchSpec,
                            InfeasibleSpace, _resolve_search_config,
                            bo_maximize, bo_maximize_many, score_topk)
-from repro.core.cache import LRUCache, counters_snapshot
+from repro.core.cache import LRUCache
 from repro.core.config import (CodesignConfig, EngineConfig, SWSearchConfig,
                                config_from_legacy_kwargs)
 from repro.core.hwspace import HardwareSpace
@@ -665,10 +666,12 @@ class SearchSession:
             callback=hw_callback,
             prior=self._prior_from_rows(prior, mean_fn) if prior else None,
             prior_mean_fn=mean_fn,
+            gp_span="codesign.outer_gp",
         )
         self._cache_counts0 = (engine.cache.hits, engine.cache.misses,
                                engine.cache.evictions)
-        self._feat_counts0 = counters_snapshot()
+        self._feat_counts0 = trace.counters_snapshot()
+        self.trace_id = trace.next_search_id()
 
     def _make_bound_mean_fn(self):
         """Prior-mean closure for the outer GP (`hw.warm_start_bound_mean`):
@@ -781,8 +784,10 @@ class SearchSession:
 
     def step(self) -> bool:
         """Advance one outer stage (the warmup block, then one hardware trial
-        per call); returns True while the session has more work."""
-        return self.loop.step()
+        per call); returns True while the session has more work.  Traced as
+        `codesign.outer`, its spans tagged with this session's `trace_id`."""
+        with trace.span("codesign.outer", search=self.trace_id):
+            return self.loop.step()
 
     def pending(self):
         """(items, seeds): the uncached (hw, layer) inner searches the next
@@ -833,7 +838,7 @@ class SearchSession:
         stats["cache_evictions"] = engine.cache.evictions - e0
         stats["cache_size"] = len(engine.cache)
         stats["prior_rows"] = self.n_prior
-        feat = counters_snapshot()
+        feat = trace.counters_snapshot()
         for key in ("hw_feat", "sw_feat", "sw_fwd"):
             for kind in ("hits", "misses"):
                 name = f"{key}_{kind}"

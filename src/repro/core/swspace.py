@@ -29,6 +29,7 @@ import os
 
 import numpy as np
 
+from repro.core import trace
 from repro.core.cache import SlotCache
 from repro.core.config import BACKENDS, PALLAS_MODES, validate_choice
 from repro.timeloop import batch as tlb
@@ -98,8 +99,9 @@ class SoftwareSpace:
 
         out = self._fwd_cache.get(pool)
         if out is None:
-            out = jtlb.forward_device(
-                self.hw, pool, self.layer, mode=self.pallas_mode)
+            with trace.span("codesign.forward"):
+                out = jtlb.forward_device(
+                    self.hw, pool, self.layer, mode=self.pallas_mode)
             self._fwd_cache.put(pool, out)
         return out
 
@@ -165,7 +167,7 @@ class SoftwareSpace:
 
     def features_batch(self, pool: tlb.MappingBatch) -> np.ndarray:
         if self.backend == "jax":
-            return np.asarray(self._forward_jax(pool)["features"])
+            return trace.fetch(self._forward_jax(pool)["features"])
         feats = self._np_feat_cache.get(pool)
         if feats is None:
             feats = tlb.features_batch(pool, self.hw, self.layer)
@@ -177,7 +179,7 @@ class SoftwareSpace:
         -inf on infeasible rows."""
         if self.backend == "jax":
             out = self._forward_jax(pool)
-            return np.asarray(out["utility"]), np.asarray(out["valid"])
+            return trace.fetch(out["utility"]), trace.fetch(out["valid"])
         ev = tlb.evaluate_batch(self.hw, pool, self.layer)
         feasible = ev["valid"]
         with np.errstate(divide="ignore", invalid="ignore"):
@@ -278,18 +280,12 @@ class LayerStackSpace:
     def n_runs(self) -> int:
         return len(self.spaces)
 
-    def placeholder_pool(self, n: int) -> tlb.MappingBatch:
-        """All-ones pool of length n: benign rows (finite arithmetic, invalid
-        under the factorization check) used to keep the stacked program's
-        (L, B) shape fixed when some runs sit a round out (no surrogate yet,
-        or stopped early) -- a varying run count would recompile the fused
-        program."""
-        return tlb.MappingBatch(
-            factors=np.ones((n, 5, 6), np.int64),
-            order_lb=np.tile(np.arange(6, dtype=np.int64), (n, 1)),
-            order_gb=np.tile(np.arange(6, dtype=np.int64), (n, 1)),
-            order_dram=np.tile(np.arange(6, dtype=np.int64), (n, 1)),
-        )
+    def placeholder_pool(self, n: int) -> tlb.PaddingPool:
+        """All-ones pool of length n (`batch.PaddingPool`) used to keep the
+        stacked program's (L, B) shape fixed when some runs sit a round out
+        (no surrogate yet, or stopped early) -- a varying run count would
+        recompile the fused program."""
+        return tlb.PaddingPool.of(n)
 
     def _forward_stacked_jax(self, pools) -> dict:
         from repro.timeloop import batch_jax as jtlb
@@ -305,37 +301,43 @@ class LayerStackSpace:
         `runs` restricts the NumPy path to the listed run indices (other rows
         stay zero) -- rounds where only a subset of runs participates; the JAX
         path always evaluates the full fixed-(L, B) fused program instead,
-        because a shape that tracked the subset would recompile it."""
+        because a shape that tracked the subset would recompile it.  Traced
+        as `codesign.forward`."""
         B = len(pools[0])
         assert all(len(p) == B for p in pools)
-        if self.backend == "jax":
-            out = self._forward_stacked_jax(pools)
-            return {k: np.asarray(out[k])
-                    for k in ("features", "utility", "valid")}
-        L = self.n_runs
-        feats = np.zeros((L, B, self.spaces[0].feature_dim))
-        utility = np.full((L, B), -np.inf)
-        valid = np.zeros((L, B), dtype=bool)
-        for k in range(L) if runs is None else runs:
-            feats[k] = self.spaces[k].features_batch(pools[k])
-            utility[k], valid[k] = self.spaces[k].evaluate_batch(pools[k])
-        return {"features": feats, "utility": utility, "valid": valid}
+        with trace.span("codesign.forward"):
+            if self.backend == "jax":
+                out = self._forward_stacked_jax(pools)
+                return {k: trace.fetch(out[k])
+                        for k in ("features", "utility", "valid")}
+            L = self.n_runs
+            feats = np.zeros((L, B, self.spaces[0].feature_dim))
+            utility = np.full((L, B), -np.inf)
+            valid = np.zeros((L, B), dtype=bool)
+            for k in range(L) if runs is None else runs:
+                feats[k] = self.spaces[k].features_batch(pools[k])
+                utility[k], valid[k] = self.spaces[k].evaluate_batch(pools[k])
+            return {"features": feats, "utility": utility, "valid": valid}
 
     def features_stacked(self, pools, runs=None) -> np.ndarray:
         """(L, B, 14) host feature tensor only -- the per-trial scoring input.
         On NumPy this skips the EDP evaluation entirely (the sequential BO
-        trial only featurizes its pool; the winner is evaluated scalar)."""
+        trial only featurizes its pool; the winner is evaluated scalar).
+        Traced as `codesign.forward`."""
         B = len(pools[0])
         assert all(len(p) == B for p in pools)
-        if self.backend == "jax":
-            return np.asarray(self._forward_stacked_jax(pools)["features"])
-        feats = np.zeros((self.n_runs, B, self.spaces[0].feature_dim))
-        for k in range(self.n_runs) if runs is None else runs:
-            feats[k] = self.spaces[k].features_batch(pools[k])
-        return feats
+        with trace.span("codesign.forward"):
+            if self.backend == "jax":
+                return trace.fetch(
+                    self._forward_stacked_jax(pools)["features"])
+            feats = np.zeros((self.n_runs, B, self.spaces[0].feature_dim))
+            for k in range(self.n_runs) if runs is None else runs:
+                feats[k] = self.spaces[k].features_batch(pools[k])
+            return feats
 
     def features_stacked_device(self, pools):
         """(L, B, 14) device-resident features for the fused multi-run GP
-        scoring chain (JAX backend only)."""
+        scoring chain (JAX backend only).  Traced as `codesign.forward`."""
         assert self.supports_device
-        return self._forward_stacked_jax(pools)["features"]
+        with trace.span("codesign.forward"):
+            return self._forward_stacked_jax(pools)["features"]

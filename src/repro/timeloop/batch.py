@@ -87,6 +87,22 @@ class MappingBatch:
         )
 
 
+@dataclasses.dataclass(frozen=True)
+class PaddingPool(MappingBatch):
+    """A pool of all-ones rows: benign (finite arithmetic, invalid under the
+    factorization check) rows that hold the place of a run sitting a round
+    out of a stacked program, so the program's shape stays fixed.  The
+    forward counts none of them as mapping rows (`forward.rows`,
+    `repro.core.trace`)."""
+
+    @classmethod
+    def of(cls, n: int) -> "PaddingPool":
+        order = np.tile(np.arange(6, dtype=np.int64), (n, 1))
+        return cls(factors=np.ones((n, 5, 6), np.int64),
+                   order_lb=order, order_gb=order.copy(),
+                   order_dram=order.copy())
+
+
 def pack(mappings: list[Mapping] | tuple[Mapping, ...]) -> MappingBatch:
     """Pack scalar `Mapping`s into a `MappingBatch`."""
     dim_idx = {d: j for j, d in enumerate(DIMS)}

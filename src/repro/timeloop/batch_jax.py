@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.kernels.edp_reduce import edp_reduce, reduce_edp_terms
 from repro.timeloop.arch import HardwareConfig
 from repro.timeloop.batch import (
@@ -62,6 +63,7 @@ from repro.timeloop.batch import (
     L_SX,
     L_SY,
     MappingBatch,
+    PaddingPool,
     REL_MASKS,
     TENSORS,
 )
@@ -253,16 +255,26 @@ def forward_device(
         factors[:B] = mb.factors
         orders[0, :B] = mb.order_gb
         orders[1, :B] = mb.order_dram
+    _count(0 if isinstance(mb, PaddingPool) else B, b)
+    to_device = trace.to_device
     with _dtype_scope(dtype):
         out = _forward(
-            jnp.asarray(factors, dtype),
-            jnp.asarray(orders[0], jnp.int32),
-            jnp.asarray(orders[1], jnp.int32),
-            jnp.asarray(np.broadcast_to(hw_vec(hw), (b, 15)), dtype),
-            jnp.asarray(np.broadcast_to(layer_vec(layer), (b, 8)), dtype),
+            to_device(factors, dtype),
+            to_device(orders[0], jnp.int32),
+            to_device(orders[1], jnp.int32),
+            to_device(np.broadcast_to(hw_vec(hw), (b, 15)), dtype),
+            to_device(np.broadcast_to(layer_vec(layer), (b, 8)), dtype),
             mode=mode,
         )
     return {k: v[:B] for k, v in out.items()}
+
+
+def _count(rows: int, slots: int) -> None:
+    """One forward program launched over `slots` rows, `rows` of them a
+    pool's own mappings (`repro.core.trace` counters)."""
+    trace.COUNTERS["forward.rows"] += rows
+    trace.COUNTERS["forward.slots"] += slots
+    trace.dispatched()
 
 
 def forward_device_stacked(
@@ -302,13 +314,16 @@ def forward_device_stacked(
             orders[1, k, :n] = p.order_dram
     layv = np.repeat(layer_vecs(layers)[:, None, :], b, axis=1)
     hwv = np.repeat(hw_vecs(hws)[:, None, :], b, axis=1)
+    _count(sum(len(p) for p in pools if not isinstance(p, PaddingPool)),
+           L * b)
+    to_device = trace.to_device
     with _dtype_scope(dtype):
         out = _forward(
-            jnp.asarray(factors.reshape(L * b, N_LEVELS, N_DIMS), dtype),
-            jnp.asarray(orders[0].reshape(L * b, N_DIMS), jnp.int32),
-            jnp.asarray(orders[1].reshape(L * b, N_DIMS), jnp.int32),
-            jnp.asarray(hwv.reshape(L * b, 15), dtype),
-            jnp.asarray(layv.reshape(L * b, 8), dtype),
+            to_device(factors.reshape(L * b, N_LEVELS, N_DIMS), dtype),
+            to_device(orders[0].reshape(L * b, N_DIMS), jnp.int32),
+            to_device(orders[1].reshape(L * b, N_DIMS), jnp.int32),
+            to_device(hwv.reshape(L * b, 15), dtype),
+            to_device(layv.reshape(L * b, 8), dtype),
             mode=mode,
         )
     return {k: v.reshape(L, b, *v.shape[1:])[:, :B] for k, v in out.items()}
@@ -360,10 +375,11 @@ def edp_lower_bounds_device(hws, layers, dtype: str | None = None) -> np.ndarray
     if n:
         hwv[:n] = hw_vecs(hws)
     with _dtype_scope(dtype):
-        out = _lower_bounds(jnp.asarray(hwv, dtype),
-                            jnp.asarray(layer_bound_vecs(layers), dtype),
-                            jnp.asarray(layer_caps(layers), dtype))
-    return np.asarray(out)[:n]
+        out = _lower_bounds(trace.to_device(hwv, dtype),
+                            trace.to_device(layer_bound_vecs(layers), dtype),
+                            trace.to_device(layer_caps(layers), dtype))
+    trace.dispatched()
+    return trace.fetch(out)[:n]
 
 
 # --- host-facing twins of the NumPy engine -------------------------------------
