@@ -43,7 +43,7 @@ searches (the nested scheme's per-layer software searches of one hardware
 probe) in lockstep, so per-round work that the sequential path repeats L times
 collapses into one batched program each — one fused device evaluation over all
 runs' candidate pools (`LayerStackSpace` packs them into a single (L*B, 5, 6)
-batch), one batched GP fit over all runs' surrogates (`GPStack`, a `lax.map`
+batch), one batched GP fit over all runs' surrogates (`GPStack`, a `vmap`
 program), one stacked posterior + acquisition + classifier chain.  Each run
 keeps its own RNG stream
 (seeded exactly as `bo_maximize(seed=...)` would be), its own observation

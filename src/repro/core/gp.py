@@ -8,7 +8,7 @@ pad X/y to bucketed sizes (powers of two) with masked-out rows so the compiled
 function is reused across BO iterations.
 
 `GPStack` / `GPClassifierStack` fit and query L *independent* GPs as one
-batched program (`lax.map` over the leading run axis: batched Cholesky
+batched program (`vmap` over the leading run axis: batched Cholesky
 solves for the fit, one device posterior over the stacked candidate pools).
 The layer-batched nested search uses this to replace L sequential per-layer
 surrogate refits -- the end-to-end bottleneck once the evaluation engine is
@@ -44,7 +44,7 @@ _JITTER = 1e-6
 _PAD_NOISE = 1e6  # effective infinite noise on padded rows -> zero influence
 # Stacked linear-kernel fits switch to the O(n d^2) Woodbury NLL above this
 # many (padded) data rows; below it the O(n^3) Cholesky NLL is cheap and keeps
-# the stacked fit bit-identical to the sequential one (see `_fit_stack`).
+# the stacked fit within f64 roundoff of the sequential one (see `_fit_stack`).
 _LOWRANK_MIN_ROWS = 32
 
 
@@ -465,12 +465,15 @@ class GPClassifier:
 def _fit_stack(params, X, y, mask, kind, steps, train_tau):
     """Batched `_fit` over the leading run axis (params leaves lead with L).
 
-    `lax.map` rather than `vmap`: one compiled program / one dispatch either
-    way, but per-slice execution keeps the single-GP linalg kernels, which on
-    CPU beat the batched-cholesky lowering badly as the data bucket grows
-    (~2.5x at 128 rows) while matching it below.  Per-slice numerics are the
-    single-run `_fit`'s exactly.  (On accelerators with real batched linalg
-    the vmap form may win again -- revisit with a hardware run.)
+    `vmap`, not a loop over runs: the Adam scan runs once for the whole
+    stack, each step on (L, ...) operands, so a fit's chain of dependent
+    device ops is as long as one run's whether the stack holds 1 run or 16.
+    (A `lax.map` runs the L scans one after another: on the TPU a fit then
+    costs L times one run's chain of small emulated-f64 ops.)  The runs stay
+    independent -- no op of the batched program mixes slices, so a run's fit
+    does not depend on its partners or its place in the stack -- but batched
+    linalg rounds differently from the single-run kernels, so a slice matches
+    the single-run `_fit` to f64 roundoff rather than bit for bit.
 
     Above `_LOWRANK_MIN_ROWS` data rows the linear kernel (the objective
     surrogate) fits through the Woodbury NLL (`lowrank=True`): the per-trial
@@ -479,24 +482,23 @@ def _fit_stack(params, X, y, mask, kind, steps, train_tau):
     to f64 roundoff, but through the ill-conditioned quad-term subtraction its
     gradients drift from the Cholesky path's by ~1e-8 relative, which after 80
     Adam steps perturbs the posterior at the ~1e-7 level -- statistically
-    nothing, but not the bit-identical-to-sequential regime the small buckets
+    nothing, but further from the sequential fits than the small buckets
     keep (the bucket is a static shape, so the switch is deterministic and
-    visible in the jit cache, and searches that never exceed the threshold
-    reproduce L sequential `bo_maximize` runs exactly)."""
+    visible in the jit cache)."""
     lowrank = kind == "linear" and X.shape[1] > _LOWRANK_MIN_ROWS
-    return jax.lax.map(
-        lambda a: _fit(a[0], a[1], a[2], a[3], kind, steps, 0.05, train_tau,
-                       lowrank=lowrank),
-        (params, X, y, mask))
+    return jax.vmap(
+        lambda p, xx, yy, mm: _fit(p, xx, yy, mm, kind, steps, 0.05,
+                                   train_tau, lowrank=lowrank)
+    )(params, X, y, mask)
 
 
 @functools.partial(jax.jit, static_argnames=("kind",))
 def _posterior_stack(params, X, y, mask, Xs, kind):
-    """Batched `_posterior`: (L, P, d) pools -> (L, P) mu/var (lax.map, see
-    `_fit_stack`)."""
-    return jax.lax.map(
-        lambda a: _posterior(a[0], a[1], a[2], a[3], a[4], kind),
-        (params, X, y, mask, Xs))
+    """Batched `_posterior`: (L, P, d) pools -> (L, P) mu/var (`vmap` over
+    the run axis, as `_fit_stack`)."""
+    return jax.vmap(
+        lambda p, xx, yy, mm, xs: _posterior(p, xx, yy, mm, xs, kind)
+    )(params, X, y, mask, Xs)
 
 
 def _bucket_stack(n: int) -> int:
@@ -557,10 +559,10 @@ def _score_stack(params, X, y, mask, feats, best, kind, acq_fn):
 @dataclasses.dataclass
 class GPStack:
     """L independent exact GP regressors, fit and queried as one batched
-    program.  Per-slice numerics match the individual `GP` (same `_fit` /
-    `_posterior` bodies per slice of a `lax.map`; padding is exactly
-    zero-influence),
-    so a stacked multi-run BO engine reproduces L sequential runs.
+    program.  Per-slice numerics match the individual `GP` to f64 roundoff
+    (the same `_fit` / `_posterior` bodies, `vmap`ped over the runs; padding
+    is exactly zero-influence), so a stacked multi-run BO engine reproduces
+    L sequential runs.
 
     kind / noisy / steps: as on `GP`, shared across the stack (the runs are
     peers -- per-layer searches of one hardware probe).

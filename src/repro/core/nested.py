@@ -324,8 +324,8 @@ class SpeculativeProbes(ProbeFanoutProbes):
             # Bucketed fan-out width on jax: pad the stack to a whole number
             # of probes so the per-round fused program compiles for at most
             # spec_k distinct run counts as cached probes drop out of later
-            # trials' top-k, while padding (real redundant runs -- lax.map GP
-            # slices are NOT free on CPU) stays under one probe's worth.
+            # trials' top-k, while padding (real redundant runs -- batched GP
+            # slices are not free) stays under one probe's worth.
             pad_to=-(-len(items) // n_layers) * n_layers)
         for (hw, layer), entry in zip(items, entries):
             engine.cache[(hw, layer)] = entry

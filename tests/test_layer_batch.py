@@ -190,6 +190,70 @@ def test_gp_stack_lowrank_regime_close_to_cholesky():
         np.testing.assert_allclose(mu_s[k], mu, atol=1e-5)
 
 
+def _fit_and_score_stack(X, y, mask, pools):
+    """`GPStack.fit`'s linear objective fit and posterior on padded stacks,
+    called directly so a slice of the stack keeps the stack's bucket."""
+    import jax.numpy as jnp
+
+    from repro.core import gp
+
+    L, _, d = X.shape
+    n = mask.sum(axis=1)
+    mean = (y * mask).sum(axis=1) / n
+    std = np.sqrt((((y - mean[:, None]) * mask) ** 2).sum(axis=1) / n)
+    with jax.enable_x64(True):
+        params = jax.tree.map(
+            lambda leaf: jnp.broadcast_to(leaf, (L, *leaf.shape)),
+            gp._init_params("linear", d))
+        params = dict(params, mean_const=jnp.asarray(mean),
+                      log_tau=jnp.asarray(np.log(np.maximum(std, 1e-3) * 0.1)))
+        args = [jnp.asarray(a) for a in (X, y, mask)]
+        fitted = gp._fit_stack(params, *args, "linear", 80, True)
+        mu, var = gp._posterior_stack(fitted, *args, jnp.asarray(pools),
+                                      "linear")
+        return ({k: np.asarray(v) for k, v in fitted.items()},
+                np.asarray(mu), np.asarray(var))
+
+
+@pytest.mark.parametrize("bucket, atol", [(32, 1e-10), (40, 1e-8)],
+                         ids=["cholesky", "woodbury"])
+def test_gp_stack_runs_fit_alone_as_in_the_batch(bucket, atol):
+    """Batching the run axis couples no runs: each run of a ragged 16-run
+    linear stack, fit alone in a stack of 1 at the same bucket, gives the
+    same hyperparameters and posterior; so does the stack in reverse order.
+    Bucket 32 fits through the Cholesky NLL, bucket 40 through Woodbury."""
+    from repro.core import gp
+
+    rng = np.random.default_rng(4)
+    L, d, P = 16, 14, 9
+    sizes = rng.integers(bucket - 12, bucket + 1, size=L)
+    sizes[3] = bucket
+    Xs = [rng.normal(size=(n, d)) for n in sizes]
+    ys = [X @ rng.normal(size=d) + 0.05 * rng.normal(size=len(X)) for X in Xs]
+    X, y, mask = gp._pad_runs(Xs, ys)
+    assert X.shape[1] == bucket
+    assert (bucket > gp._LOWRANK_MIN_ROWS) == (bucket == 40)
+    pools = rng.normal(size=(L, P, d))
+    params, mu, var = _fit_and_score_stack(X, y, mask, pools)
+    rev = slice(None, None, -1)
+    params_r, mu_r, var_r = _fit_and_score_stack(X[rev], y[rev], mask[rev],
+                                                 pools[rev])
+    for k in range(L):
+        one = slice(k, k + 1)
+        params_1, mu_1, var_1 = _fit_and_score_stack(X[one], y[one],
+                                                     mask[one], pools[one])
+        for name, leaf in params.items():
+            np.testing.assert_allclose(leaf[k], params_1[name][0], rtol=0,
+                                       atol=atol)
+            np.testing.assert_allclose(leaf[k], params_r[name][L - 1 - k],
+                                       rtol=0, atol=atol)
+        for stacked, alone, reverse in ((mu, mu_1, mu_r), (var, var_1, var_r)):
+            np.testing.assert_allclose(stacked[k], alone[0], rtol=0,
+                                       atol=atol)
+            np.testing.assert_allclose(stacked[k], reverse[L - 1 - k],
+                                       rtol=0, atol=atol)
+
+
 def test_gp_classifier_stack_matches_individual():
     rng = np.random.default_rng(2)
     Xs = [rng.normal(size=(n, 3)) for n in (18, 30)]

@@ -71,17 +71,37 @@ def test_forward_pallas_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_gp_stack_fit_compiles_for_v5e_in_f64(one_chip):
-    runs = 4
+def _stack_params(one_chip, runs):
+    init = dict(gp._init_params("linear", N_FEATURES),
+                mean_const=jnp.zeros(()), log_tau=jnp.zeros(()))
+    return {k: _spec(one_chip, (runs, *v.shape), jnp.float64)
+            for k, v in init.items()}
+
+
+# (4, 256): the inner search's last bucket at the paper's 250 trials;
+# (16, 32) and (16, 40): the cells' widest stacks, Cholesky and Woodbury.
+@pytest.mark.parametrize("runs, bucket", [(4, STACK_BUCKET), (16, 32),
+                                          (16, 40)])
+def test_gp_stack_fit_compiles_for_v5e_in_f64(one_chip, runs, bucket):
     with jax.enable_x64(True):
-        init = dict(gp._init_params("linear", N_FEATURES),
-                    mean_const=jnp.zeros(()), log_tau=jnp.zeros(()))
-        params = {k: _spec(one_chip, (runs, *v.shape), jnp.float64)
-                  for k, v in init.items()}
         compiled = gp._fit_stack.lower(
-            params,
-            _spec(one_chip, (runs, STACK_BUCKET, N_FEATURES), jnp.float64),
-            _spec(one_chip, (runs, STACK_BUCKET), jnp.float64),
-            _spec(one_chip, (runs, STACK_BUCKET), jnp.float64),
+            _stack_params(one_chip, runs),
+            _spec(one_chip, (runs, bucket, N_FEATURES), jnp.float64),
+            _spec(one_chip, (runs, bucket), jnp.float64),
+            _spec(one_chip, (runs, bucket), jnp.float64),
             kind="linear", steps=80, train_tau=True).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_gp_stack_score_compiles_for_v5e_in_f64(one_chip):
+    runs, bucket, pool = 16, 40, 256
+    with jax.enable_x64(True):
+        compiled = gp._score_stack.lower(
+            _stack_params(one_chip, runs),
+            _spec(one_chip, (runs, bucket, N_FEATURES), jnp.float64),
+            _spec(one_chip, (runs, bucket), jnp.float64),
+            _spec(one_chip, (runs, bucket), jnp.float64),
+            _spec(one_chip, (runs, pool, N_FEATURES), jnp.float64),
+            _spec(one_chip, (runs, 1), jnp.float64),
+            kind="linear", acq_fn=gp._acq_device_cached("lcb", 1.0)).compile()
     assert compiled.memory_analysis() is not None
