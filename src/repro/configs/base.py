@@ -31,9 +31,24 @@ class ModelConfig:
     # recurrent details
     rglru_conv_width: int = 4
     mlstm_chunk: int = 256           # chunkwise-parallel mLSTM chunk length
+    # multi-head latent attention (MLA, DeepSeek-V2/V3), on when
+    # kv_lora_rank > 0: keys and values are up-projected per head from one
+    # shared latent of kv_lora_rank plus a qk_rope_head_dim rotary key, and
+    # that latent is what the cache holds.  Queries are projected straight
+    # from d_model (no q_lora_rank).  head_dim and num_kv_heads are unused.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # moe details
     num_experts: int = 0
     top_k: int = 0
+    num_shared_experts: int = 0      # always-on experts, each d_ff wide
+    # leading dense layers of a MoE model (first_k_dense_replace): the first
+    # dense_layers blocks run a dense MLP dense_d_ff wide in place of experts
+    dense_layers: int = 0
+    dense_d_ff: int = 0
+    tie_embeddings: bool = True
     # encoder-decoder
     encoder_layers: int = 0          # >0 -> enc-dec; decoder uses num_layers
     # modality frontend stub: "tokens" or "embeddings"
@@ -57,6 +72,18 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         assert self.num_layers % len(self.block_pattern) == 0, (
             self.name, "block pattern period must divide num_layers")
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    def layer_kinds(self) -> tuple[str, ...]:
+        """The block kind of each layer: the pattern cycled, with "dense"
+        for the leading dense layers."""
+        period = len(self.block_pattern)
+        return tuple("dense" if i < self.dense_layers
+                     else self.block_pattern[i % period]
+                     for i in range(self.num_layers))
 
     @property
     def sub_quadratic(self) -> bool:

@@ -584,6 +584,8 @@ class GPStack:
         ys = [np.asarray(yk, np.float64) for yk in ys]
         X, y, mask = _pad_runs(Xs, ys)
         L, b, d = X.shape
+        trace.COUNTERS["gp.fits"] += 1
+        trace.COUNTERS["gp.runs"] += L
         trace.COUNTERS["gp.rows"] += sum(len(yk) for yk in ys)
         trace.COUNTERS["gp.slots"] += L * b
         to_device = trace.to_device

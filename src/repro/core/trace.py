@@ -12,6 +12,10 @@ difference of two snapshots is the reading over the stretch between them.
                        (`batch.PaddingPool`) are not counted
   forward.slots        rows the forward program ran: the pool length rounded
                        up to its bucket, times the runs of a stack
+  gp.fits              stacked GP fits (`GPStack.fit`, which
+                       `GPClassifierStack.fit` calls once a fit)
+  gp.runs              runs those fits stacked: gp.runs / gp.fits is the
+                       mean stack width
   gp.rows              observations of the stacked GP fits (`GPStack.fit`)
   gp.slots             rows those fits ran: runs x `gp._bucket_stack`
   transfer.h2d_bytes   bytes copied to the device for the search's programs

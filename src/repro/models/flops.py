@@ -16,20 +16,45 @@ from __future__ import annotations
 from repro.configs.base import ModelConfig, ShapeConfig
 
 
-def _attn_flops_per_token(cfg: ModelConfig, ctx: float) -> float:
+def _attn_flops_per_token(cfg: ModelConfig, ctx: float,
+                          decode: bool = False) -> float:
+    if cfg.mla:
+        return _mla_flops_per_token(cfg, ctx, decode)
     D, H, KV, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     proj = 2 * D * (H + 2 * KV) * hd + 2 * H * hd * D
     attn = 2 * 2 * ctx * H * hd  # scores + pv
     return proj + attn
 
 
-def _mlp_flops_per_token(cfg: ModelConfig) -> float:
-    return 6 * cfg.d_model * cfg.d_ff if cfg.d_ff else 0.0
+def _mla_flops_per_token(cfg: ModelConfig, ctx: float, decode: bool) -> float:
+    """Multi-head latent attention.  Both paths project q (D -> H*(dn+dr)),
+    the latent and rotary key (D -> r+dr) and the output (H*dv -> D).
+    Train and prefill up-project the latent to per-head keys and values
+    (r -> H*(dn+dv)) and attend at head width; decode absorbs the
+    up-projections instead (q_nope -> latent per head, r*dn; the latent
+    context back to dv per head, r*dv) and attends over the cached latent
+    at width r+dr for scores and r for PV."""
+    D, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    proj = 2 * D * H * (dn + dr) + 2 * D * (r + dr) + 2 * H * dv * D
+    if decode:
+        absorb = 2 * H * dn * r + 2 * H * r * dv
+        attn = 2 * ctx * H * (r + dr) + 2 * ctx * H * r
+    else:
+        absorb = 2 * r * H * (dn + dv)
+        attn = 2 * ctx * H * (dn + dr) + 2 * ctx * H * dv
+    return proj + absorb + attn
+
+
+def _mlp_flops_per_token(cfg: ModelConfig, d_ff: int | None = None) -> float:
+    d_ff = cfg.d_ff if d_ff is None else d_ff
+    return 6 * cfg.d_model * d_ff if d_ff else 0.0
 
 
 def _moe_flops_per_token(cfg: ModelConfig) -> float:
     router = 2 * cfg.d_model * cfg.num_experts
-    return router + cfg.top_k * 6 * cfg.d_model * cfg.d_ff
+    shared = _mlp_flops_per_token(cfg, cfg.num_shared_experts * cfg.d_ff)
+    return router + cfg.top_k * 6 * cfg.d_model * cfg.d_ff + shared
 
 
 def _mlstm_flops_per_token(cfg: ModelConfig, decode: bool) -> float:
@@ -57,12 +82,16 @@ def _rglru_flops_per_token(cfg: ModelConfig) -> float:
 def _block_flops_per_token(cfg: ModelConfig, kind: str, ctx: float,
                            decode: bool) -> float:
     if kind == "attn":
-        return _attn_flops_per_token(cfg, ctx) + _mlp_flops_per_token(cfg)
+        return _attn_flops_per_token(cfg, ctx, decode) + _mlp_flops_per_token(cfg)
     if kind == "local_attn":
         local_ctx = min(ctx, float(cfg.local_window or ctx))
-        return _attn_flops_per_token(cfg, local_ctx) + _mlp_flops_per_token(cfg)
+        return (_attn_flops_per_token(cfg, local_ctx, decode)
+                + _mlp_flops_per_token(cfg))
     if kind == "moe":
-        return _attn_flops_per_token(cfg, ctx) + _moe_flops_per_token(cfg)
+        return _attn_flops_per_token(cfg, ctx, decode) + _moe_flops_per_token(cfg)
+    if kind == "dense":  # a MoE model's leading dense layer
+        return (_attn_flops_per_token(cfg, ctx, decode)
+                + _mlp_flops_per_token(cfg, cfg.dense_d_ff))
     if kind == "mlstm":
         return _mlstm_flops_per_token(cfg, decode)
     if kind == "slstm":
@@ -83,10 +112,9 @@ def forward_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
         tokens = float(B) * S
         ctx = S / 2.0              # causal average context
 
-    per_tok = sum(_block_flops_per_token(cfg, k, ctx, decode)
-                  for k in cfg.block_pattern) / len(cfg.block_pattern)
-    total = tokens * per_tok * cfg.num_layers
-    # unembed (tied): logits for every processed token in train; last/one token
+    total = tokens * sum(_block_flops_per_token(cfg, k, ctx, decode)
+                         for k in cfg.layer_kinds())
+    # unembed: logits for every processed token in train; last/one token
     # in prefill/decode
     V = cfg.padded_vocab()
     if shape.kind == "train":
@@ -108,15 +136,23 @@ def forward_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
 def param_count(cfg: ModelConfig) -> float:
     """Total parameters from the config (cheap, no tracing)."""
     D, V = cfg.d_model, cfg.padded_vocab()
-    per_layer = 0.0
-    for kind in cfg.block_pattern:
-        H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    if cfg.mla:
+        r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                         cfg.qk_rope_head_dim, cfg.v_head_dim)
+        attn = D * H * (dn + dr) + D * (r + dr) + r * H * (dn + dv) + H * dv * D
+    else:
         attn = D * (H + 2 * KV) * hd + H * hd * D
-        mlp = 3 * D * cfg.d_ff
+    mlp = 3 * D * cfg.d_ff
+    per_layer = 0.0
+    for kind in cfg.layer_kinds():
         if kind in ("attn", "local_attn"):
             per_layer += attn + mlp
+        elif kind == "dense":
+            per_layer += attn + 3 * D * cfg.dense_d_ff
         elif kind == "moe":
-            per_layer += attn + D * cfg.num_experts + cfg.num_experts * 3 * D * cfg.d_ff
+            experts = cfg.num_experts + cfg.num_shared_experts
+            per_layer += attn + D * cfg.num_experts + experts * 3 * D * cfg.d_ff
         elif kind == "mlstm":
             Din = 2 * D
             per_layer += 2 * D * Din + Din * D + 3 * Din * (Din // H) + 2 * Din * H
@@ -125,7 +161,7 @@ def param_count(cfg: ModelConfig) -> float:
             per_layer += 4 * (D * D + D * (D // H)) + 3 * D * F + D * D
         elif kind == "rglru":
             per_layer += 5 * D * D + mlp
-    total = V * D + per_layer * cfg.num_layers / len(cfg.block_pattern)
+    total = V * D * (1 if cfg.tie_embeddings else 2) + per_layer
     if cfg.family == "encdec":
         total += (4 * D * D + 3 * D * cfg.d_ff) * cfg.encoder_layers
         total += 4 * D * D * cfg.num_layers  # cross-attention

@@ -12,6 +12,19 @@ from repro.models.lm import LM
 
 
 def build_model(cfg: ModelConfig):
+    """The LM (or encoder-decoder) of `cfg`.  Raises NotImplementedError for
+    a config that needs what the LM stack does not build (latent attention,
+    shared experts, leading dense layers, untied embeddings), rather than
+    building a different model under its name."""
+    missing = [what for what, needed in (
+        ("multi-head latent attention", cfg.mla),
+        ("shared experts", cfg.num_shared_experts),
+        ("leading dense layers", cfg.dense_layers),
+        ("untied embeddings", not cfg.tie_embeddings)) if needed]
+    if missing:
+        raise NotImplementedError(
+            f"{cfg.name}: the LM stack does not implement "
+            f"{', '.join(missing)}")
     return EncDecLM(cfg) if cfg.family == "encdec" else LM(cfg)
 
 

@@ -63,6 +63,7 @@ def test_depth_variant_preserves_pattern():
     ("qwen3-14b", 13e9, 17e9),
     ("stablelm-12b", 11e9, 14e9),
     ("qwen2-vl-72b", 65e9, 80e9),
+    ("moonshot-v1-16b-a3b", 15e9, 17e9),   # "16B" total, 15.96e9 counted
 ])
 def test_param_count_plausible(arch, lo, hi):
     n = param_count(get_config(arch))

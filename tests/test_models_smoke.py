@@ -149,6 +149,27 @@ def test_int8_kv_cache_roundtrip():
     assert float(jnp.max(jnp.abs(back - x))) < float(jnp.max(jnp.abs(x))) / 127 * 1.01
 
 
+@pytest.mark.parametrize("change,what", [
+    ({"kv_lora_rank": 32, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+      "v_head_dim": 16}, "multi-head latent attention"),
+    ({"num_shared_experts": 1}, "shared experts"),
+    ({"dense_layers": 1, "dense_d_ff": 128}, "leading dense layers"),
+    ({"tie_embeddings": False}, "untied embeddings"),
+])
+def test_build_model_refuses_what_the_lm_stack_lacks(change, what):
+    """A config the LM stack cannot build is refused, not silently built as
+    plain MHA / plain MoE under its name; the smoke config still builds."""
+    cfg = dataclasses.replace(get_smoke_config("moonshot-v1-16b-a3b"), **change)
+    with pytest.raises(NotImplementedError, match=what):
+        build_model(cfg)
+    build_model(get_smoke_config("moonshot-v1-16b-a3b"))
+
+
+def test_build_model_refuses_the_published_moonlight_config():
+    with pytest.raises(NotImplementedError, match="latent attention"):
+        build_model(get_config("moonshot-v1-16b-a3b"))
+
+
 def test_long_500k_skip_rules():
     shape = SHAPES["long_500k"]
     runs = {a: cell_is_applicable(get_config(a), shape)[0] for a in ARCH_IDS}

@@ -9,15 +9,16 @@ import pytest
 
 from repro.core import (CodesignConfig, CodesignEngine, EngineConfig,
                         HWSearchConfig, SWSearchConfig, cache, trace)
-from repro.core.gp import GPStack
+from repro.core.gp import GPClassifierStack, GPStack
 from repro.timeloop import MODEL_LAYERS, eyeriss_168
 from repro.timeloop import batch as tlb
 from repro.timeloop import batch_jax as jtlb
 
 LAYER_SPANS = ("codesign.outer", "codesign.outer_gp", "codesign.inner",
                "codesign.sample", "codesign.forward", "codesign.gp")
-COUNTERS = ("forward.rows", "forward.slots", "gp.rows", "gp.slots",
-            "transfer.h2d_bytes", "transfer.d2h_bytes", "device.dispatches")
+COUNTERS = ("forward.rows", "forward.slots", "gp.fits", "gp.runs", "gp.rows",
+            "gp.slots", "transfer.h2d_bytes", "transfer.d2h_bytes",
+            "device.dispatches")
 
 
 @pytest.fixture
@@ -129,6 +130,22 @@ def test_counters_count_with_tracing_off():
     # the per-run mean and noise
     assert got["transfer.h2d_bytes"] == 2 * 2 * 8 * (14 + 1 + 1) * 8 + 2 * 2 * 8
     assert trace.spans() == []
+
+
+def test_gp_fit_counters_read_the_stack_width():
+    """gp.fits counts stacked fits and gp.runs their runs, so gp.runs /
+    gp.fits is the mean stack width; a classifier stack's fit is one fit
+    (it runs through `GPStack.fit` once)."""
+    rng = np.random.default_rng(1)
+    X = [rng.normal(size=(n, 6)) for n in (4, 5, 6)]
+    before = trace.counters_snapshot()
+    GPStack(kind="linear").fit(X, [rng.normal(size=len(x)) for x in X])
+    got = counter_diff(before)
+    assert (got["gp.fits"], got["gp.runs"]) == (1, 3)
+    before = trace.counters_snapshot()
+    GPClassifierStack().fit(X[:2], [rng.random(len(x)) < 0.5 for x in X[:2]])
+    got = counter_diff(before)
+    assert (got["gp.fits"], got["gp.runs"]) == (1, 2)
 
 
 def test_stats_unchanged_by_the_counter_move():

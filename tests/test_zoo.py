@@ -13,7 +13,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from repro.configs.base import ARCH_IDS, get_config
+from repro.configs.base import ARCH_IDS, SHAPES, get_config
 from repro.models.flops import forward_flops
 from repro.timeloop import (MODEL_LAYERS, SAMPLER_DIVISOR_CAP, divisors,
                             eyeriss_168, sampler_divisors)
@@ -23,7 +23,9 @@ from repro.timeloop.mapping import (constrained_random_mapping,
 from repro.timeloop.workloads import _TOKENS, ConvLayer, fc
 from repro.workloads import (MACS_RTOL, ZOO_NAMES, known_workloads,
                              resolve_workload, workload_set, zoo_workload)
-from repro.workloads.zoo import ZOO_SHAPE
+from repro.workloads.zoo import (MOONLIGHT_DECODE, ZOO_SHAPE,
+                                 generate_workload, routed_tokens,
+                                 step_tokens)
 
 ZOO_GOLDEN_PATH = Path(__file__).parent / "goldens" / "zoo_workloads.json"
 
@@ -48,6 +50,10 @@ def test_macs_cross_check(name):
 @pytest.mark.parametrize("name", ZOO_NAMES)
 def test_shape_sanity(name):
     zw = zoo_workload(name)
+    cfg = get_config(zw.arch)
+    routed = ({t for t, _ in routed_tokens(_TOKENS, cfg.top_k,
+                                           cfg.num_experts)}
+              if cfg.num_experts else set())
     assert len(zw.layers) == len(zw.counts) > 0
     names = [l.name for l in zw.layers]
     assert len(set(names)) == len(names), "duplicate layer names"
@@ -60,9 +66,97 @@ def test_shape_sanity(name):
             assert layer.dim(d) >= 1
         assert layer.stride == 1
         assert layer.macs > 0
-        # GEMM encoding: token tile on P (the encoder runs a smaller tile)
-        assert layer.P in (_TOKENS, max(_TOKENS // 8, 16))
+        # GEMM encoding: token tile on P (the encoder runs a smaller tile,
+        # a routed expert its share of the tile's routed tokens)
+        assert layer.P in {_TOKENS, max(_TOKENS // 8, 16), *routed}
         assert layer.input_extent(layer.P, layer.R) >= layer.P
+
+
+# --- deployed shapes: Moonlight's decode step ------------------------------------
+
+# (role, C, K, P, count) of one Moonlight-16B-A3B decode step at batch 128 and
+# an 8192-token latent cache: 27 layers of absorbed MLA, one dense layer and
+# 26 MoE layers (64 experts top-6, 2 shared), the untied unembed.
+MOONLIGHT_DECODE_TABLE = [
+    ("attn_q", 2048, 3072, 128, 27),
+    ("attn_kv_a", 2048, 576, 128, 27),
+    ("attn_absorb_k", 128, 512, 128, 27 * 16),
+    ("attn_scores", 576, 8192, 16, 27 * 128),
+    ("attn_pv", 8192, 512, 16, 27 * 128),
+    ("attn_absorb_v", 512, 128, 128, 27 * 16),
+    ("attn_o", 2048, 2048, 128, 27),
+    ("dense_up", 2048, 11264, 128, 2),
+    ("dense_down", 11264, 2048, 128, 1),
+    ("moe_router", 2048, 64, 128, 26),
+    ("shared_up", 2048, 2816, 128, 2 * 26),
+    ("shared_down", 2816, 2048, 128, 26),
+    ("moe_up", 2048, 1408, 12, 2 * 64 * 26),
+    ("moe_down", 1408, 2048, 12, 64 * 26),
+    ("unembed", 2048, 163840, 128, 1),
+]
+
+
+def test_moonlight_decode_layers_and_counts():
+    zw = generate_workload("moonshot-v1-16b-a3b", shape=MOONLIGHT_DECODE)
+    got = [(l.name.split("-", 1)[1], l.C, l.K, l.P, c)
+           for l, c in zip(zw.layers, zw.counts)]
+    assert got == MOONLIGHT_DECODE_TABLE
+    assert all((l.R, l.S, l.Q, l.stride) == (1, 1, 1, 1) for l in zw.layers)
+    assert zw.shape == MOONLIGHT_DECODE
+
+
+@pytest.mark.parametrize("shape", [ZOO_SHAPE, MOONLIGHT_DECODE],
+                         ids=lambda s: s.name)
+def test_moonlight_macs_cross_check(shape):
+    """Coverage of forward_flops within [1 - MACS_RTOL, 1] at the training
+    tile (kv_b up-projection, scores+PV skipped) and exactly 1 at decode,
+    where every product of the absorbed path is a layer."""
+    zw = generate_workload("moonshot-v1-16b-a3b", shape=shape)
+    flops = forward_flops(get_config("moonshot-v1-16b-a3b"), shape)
+    assert 2 * zw.total_macs / flops == pytest.approx(zw.coverage)
+    assert 1.0 - MACS_RTOL <= zw.coverage <= 1.0 + 1e-9
+    if shape.kind == "decode":
+        assert 2 * zw.total_macs == flops
+
+
+@pytest.mark.parametrize("tokens,top_k,experts,want", [
+    (128, 6, 64, [(12, 64)]),     # Moonlight decode: 12 tokens per expert
+    (64, 6, 64, [(6, 64)]),       # Moonlight at the zoo tile
+    (64, 1, 128, [(1, 64)]),      # llama4: fewer routed slots than experts
+    (10, 3, 8, [(4, 6), (3, 2)]),  # uneven: as even as whole tokens allow
+])
+def test_routed_tokens(tokens, top_k, experts, want):
+    got = routed_tokens(tokens, top_k, experts)
+    assert got == want
+    assert sum(t * n for t, n in got) == tokens * top_k
+    assert sum(n for _, n in got) <= experts
+
+
+@pytest.mark.parametrize("arch,shape", [
+    ("moonshot-v1-16b-a3b", MOONLIGHT_DECODE),
+    ("moonshot-v1-16b-a3b", ZOO_SHAPE),
+    ("llama4-maverick-400b-a17b", ZOO_SHAPE),
+])
+def test_routed_expert_macs_are_exact(arch, shape):
+    """The routed experts' layers carry tokens * top_k * 3 * D * F MACs in
+    each MoE layer, each expert at its routed token count."""
+    cfg = get_config(arch)
+    zw = generate_workload(arch, shape=shape)
+    routed = sum(c * l.macs for l, c in zip(zw.layers, zw.counts)
+                 if l.name.split("-", 1)[1].startswith(("moe_up", "moe_down")))
+    n_moe = cfg.layer_kinds().count("moe")
+    T = step_tokens(shape)
+    assert routed == n_moe * T * cfg.top_k * 3 * cfg.d_model * cfg.d_ff
+    for l in zw.layers:
+        if l.name.split("-", 1)[1].startswith(("moe_up", "moe_down")):
+            assert l.P < T
+
+
+def test_plain_attention_decode_fails_the_cross_check():
+    """Plain attention's scores+PV are not layers yet: at a 32k decode they
+    are most of the step, and generation refuses rather than under-count."""
+    with pytest.raises(ValueError, match="cover"):
+        generate_workload("qwen3-14b", shape=SHAPES["decode_32k"])
 
 
 # --- registry / resolution ------------------------------------------------------
