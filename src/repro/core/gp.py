@@ -26,10 +26,20 @@ state is held as f64 device arrays, and every jnp op that touches them, or the
 f64 posteriors and utilities they produce, must run inside that scope: outside
 it jax rejects or truncates f64 operands.  Callers that leave the scope take
 host copies (`np.asarray`) at the boundary.
+
+Where the default backend is a TPU, the stacked fit runs on the host's CPU
+device (`_fit_device`).  A fit is 80 dependent Adam steps, each a Cholesky
+(or Woodbury) factorization of a few dozen rows, its solves and log-determinant
+in float64.  The TPU has no float64 units and emulates it with pairs of
+float32 ops, so there the fit is a long chain of tiny dependent ops, bound by
+latency; the host's CPU runs the same program in native IEEE float64.  The
+fitted state then moves to the chip, where the stacked scoring runs against
+the candidate pools' features.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 
@@ -461,6 +471,20 @@ class GPClassifier:
 
 # --- stacked (multi-run) GPs ----------------------------------------------------
 
+@functools.cache
+def _fit_device():
+    """The device `GPStack` fits on: the host's first CPU device where the
+    default backend is a TPU (see the module docstring), else None, the
+    default device.  Also None where no CPU backend is present
+    (`JAX_PLATFORMS` naming the TPU alone)."""
+    if jax.default_backend() != "tpu":
+        return None
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError:
+        return None
+
+
 @functools.partial(jax.jit, static_argnames=("kind", "steps", "train_tau"))
 def _fit_stack(params, X, y, mask, kind, steps, train_tau):
     """Batched `_fit` over the leading run axis (params leaves lead with L).
@@ -564,6 +588,14 @@ class GPStack:
     is exactly zero-influence), so a stacked multi-run BO engine reproduces
     L sequential runs.
 
+    The fit runs where `_fit_device` says: on the host's CPU device when the
+    default backend is a TPU, which emulates float64 (counted in
+    `gp.host_fits`).  Its params stay on the host until the first query
+    copies them to the default device, so the caller goes on (sampling the
+    next candidate pools) while the CPU fits; the padded data is copied
+    there at fit time.  The posterior and the fused scoring run on the
+    default device.
+
     kind / noisy / steps: as on `GP`, shared across the stack (the runs are
     peers -- per-layer searches of one hardware probe).
     """
@@ -572,6 +604,7 @@ class GPStack:
     noisy: bool = True
     steps: int = 80
     _state: tuple | None = None
+    _params_on_host: bool = False
 
     def fit(self, Xs, ys) -> "GPStack":
         """Fit from per-run datasets: Xs[k] is (n_k, d), ys[k] is (n_k,).
@@ -589,24 +622,44 @@ class GPStack:
         trace.COUNTERS["gp.rows"] += sum(len(yk) for yk in ys)
         trace.COUNTERS["gp.slots"] += L * b
         to_device = trace.to_device
+        host = _fit_device()
+        # Copies onto the host's CPU device stay in host memory: not counted.
+        put = to_device if host is None else jnp.asarray
         with jax.enable_x64(True):
-            params = jax.tree.map(
-                lambda leaf: jnp.broadcast_to(leaf, (L, *leaf.shape)),
-                _init_params(self.kind, d))
-            params = dict(
-                params,
-                mean_const=to_device([float(yk.mean()) for yk in ys]),
-                log_tau=to_device(
-                    [np.log(max(yk.std(), 1e-3) * 0.1) for yk in ys]
-                    if self.noisy else [-6.0] * L),
-            )
-            params = _fit_stack(params, to_device(X), to_device(y),
-                                to_device(mask), self.kind, self.steps,
-                                self.noisy)
+            with (contextlib.nullcontext() if host is None
+                  else jax.default_device(host)):
+                params = jax.tree.map(
+                    lambda leaf: jnp.broadcast_to(leaf, (L, *leaf.shape)),
+                    _init_params(self.kind, d))
+                params = dict(
+                    params,
+                    mean_const=put([float(yk.mean()) for yk in ys]),
+                    log_tau=put(
+                        [np.log(max(yk.std(), 1e-3) * 0.1) for yk in ys]
+                        if self.noisy else [-6.0] * L),
+                )
+                params = _fit_stack(params, put(X), put(y), put(mask),
+                                    self.kind, self.steps, self.noisy)
             trace.dispatched()
             self._state = (params, to_device(X), to_device(y),
                            to_device(mask))
+        self._params_on_host = host is not None
+        if self._params_on_host:
+            trace.COUNTERS["gp.host_fits"] += 1
         return self
+
+    def _fitted(self) -> tuple:
+        """The fitted state, its params copied to the default device on the
+        first query after a fit on the host (which waits for that fit)."""
+        assert self._state is not None, "fit() first"
+        if self._params_on_host:
+            params, *data = self._state
+            with jax.enable_x64(True):
+                params = jax.tree.map(
+                    lambda leaf: trace.to_device(np.asarray(leaf)), params)
+            self._state = (params, *data)
+            self._params_on_host = False
+        return self._state
 
     def __len__(self) -> int:
         return int(self._state[1].shape[0]) if self._state else 0
@@ -618,8 +671,7 @@ class GPStack:
     def posterior_device(self, Xs) -> tuple[jax.Array, jax.Array]:
         """Stacked posterior: Xs is (L, P, d) -- one candidate pool per run --
         returning (L, P) device arrays (the fused multi-run scoring path)."""
-        assert self._state is not None, "fit() first"
-        params, Xp, yp, mask = self._state
+        params, Xp, yp, mask = self._fitted()
         trace.dispatched()
         with jax.enable_x64(True):
             Xs = trace.to_device(Xs, jnp.float64)
@@ -632,10 +684,9 @@ class GPStack:
         posterior, acquisition (vs per-run incumbents `best`, shape (L, 1)),
         per-run argmax, and the winners' feature rows -- only the (L,) indices
         and (L, d) rows return to the host.  Traced as `codesign.gp`."""
-        assert self._state is not None, "fit() first"
-        params, Xp, yp, mask = self._state
         acq_fn = _acq_device_cached(acquisition, float(lam))
         with trace.span("codesign.gp"):
+            params, Xp, yp, mask = self._fitted()
             with jax.enable_x64(True):
                 idx, rows = _score_stack(
                     params, Xp, yp, mask,

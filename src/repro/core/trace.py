@@ -18,13 +18,18 @@ difference of two snapshots is the reading over the stretch between them.
                        mean stack width
   gp.rows              observations of the stacked GP fits (`GPStack.fit`)
   gp.slots             rows those fits ran: runs x `gp._bucket_stack`
+  gp.host_fits         stacked fits placed on the host's CPU device
+                       (`gp._fit_device`: where the default backend is a
+                       TPU); their copies onto the CPU device are host
+                       memory and not in transfer.h2d_bytes
   transfer.h2d_bytes   bytes copied to the device for the search's programs
                        (`to_device`)
   transfer.d2h_bytes   bytes of device results fetched to the host (`fetch`)
   device.dispatches    jitted programs launched on the search path: the
-                       forward, the EDP lower bounds, the stacked GP's fit,
-                       scoring and posterior, the outer GP's fit, posterior
-                       and rank-1 update
+                       forward, the EDP lower bounds, the stacked GP's fit
+                       (on the host's CPU too, see gp.host_fits), scoring
+                       and posterior, the outer GP's fit, posterior and
+                       rank-1 update
   trace.dropped        span records dropped because the record list was full
 
 Spans are off until `enable()` and off again after `disable()`; those two

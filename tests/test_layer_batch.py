@@ -254,6 +254,126 @@ def test_gp_stack_runs_fit_alone_as_in_the_batch(bucket, atol):
                                        rtol=0, atol=atol)
 
 
+@pytest.fixture
+def fit_device_cleared():
+    """`gp._fit_device` is worked out once a process: clear it before and
+    after a test that steers what it reads, so that neither this test nor
+    a later one sees a stale answer."""
+    from repro.core import gp
+
+    gp._fit_device.cache_clear()
+    yield gp
+    gp._fit_device.cache_clear()
+
+
+def _fit_and_query_stacks(Xs, ys, feas, pools, best):
+    """A linear `GPStack` and a `GPClassifierStack` fit on the same runs,
+    and every query of them: posterior, fused picks, P(feasible) both ways.
+    Returns the stacks (as left after the queries) and the answers."""
+    stack = GPStack(kind="linear").fit(Xs, ys)
+    clf = GPClassifierStack().fit(Xs, feas)
+    fitted_on_host = (stack._params_on_host, clf._stack._params_on_host)
+    mu, var = stack.posterior(pools)
+    idx, rows = stack.score_device(pools, best)
+    answers = (mu, var, idx, rows, clf.prob_feasible(pools),
+               np.asarray(clf.prob_feasible_device(pools)))
+    return stack, clf, fitted_on_host, answers
+
+
+@pytest.mark.parametrize("placed", [True, False],
+                         ids=["placement-on", "placement-off"])
+def test_gp_stack_fit_placed_on_the_host_cpu_under_a_tpu(
+        placed, fit_device_cleared, monkeypatch):
+    """Where the default backend is a TPU (here `jax.default_backend` is
+    made to say so), the stacked fits of `GPStack` and `GPClassifierStack`
+    run on the host's CPU device in float64, `gp.host_fits` counts them,
+    the fitted state lands on the default device, and every posterior and
+    pick is the unplaced run's bit for bit.  On a CPU-only host the CPU
+    device is also the default one, so where the fit ran is read from the
+    default device that `jax.default_device` names while `_fit_stack`
+    runs."""
+    from repro.core import trace
+
+    gp = fit_device_cleared
+    rng = np.random.default_rng(7)
+    d, P = 14, 9
+    Xs = [rng.normal(size=(n, d)) for n in (5, 12, 20)]
+    ys = [X @ rng.normal(size=d) + 0.05 * rng.normal(size=len(X))
+          for X in Xs]
+    feas = [X[:, 0] > 0 for X in Xs]
+    pools = rng.normal(size=(len(Xs), P, d))
+    best = np.array([[y.min()] for y in ys])
+    *_, reference = _fit_and_query_stacks(Xs, ys, feas, pools, best)
+
+    fits = []
+    fit_stack = gp._fit_stack
+
+    def spy(*args):
+        out = fit_stack(*args)
+        fits.append((jax.config.jax_default_device, out))
+        return out
+
+    monkeypatch.setattr(gp, "_fit_stack", spy)
+    if placed:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    gp._fit_device.cache_clear()
+    cpu, default = jax.devices("cpu")[0], jax.devices()[0]
+    before = trace.counters_snapshot()
+    stack, clf, on_host, answers = _fit_and_query_stacks(Xs, ys, feas,
+                                                         pools, best)
+    host_fits = (trace.COUNTERS["gp.host_fits"]
+                 - before.get("gp.host_fits", 0))
+    assert trace.COUNTERS["gp.fits"] - before.get("gp.fits", 0) == 2
+    assert host_fits == (2 if placed else 0)
+    assert on_host == (placed, placed)
+    assert len(fits) == 2
+    for where, params in fits:
+        assert where == (cpu if placed else None)
+        for leaf in jax.tree.leaves(params):
+            assert leaf.dtype == np.float64
+            assert leaf.devices() == {cpu}
+    for s in (stack, clf._stack):
+        assert not s._params_on_host
+        for leaf in jax.tree.leaves(s._state):
+            assert leaf.dtype == np.float64
+            assert leaf.devices() == {default}
+    for got, want in zip(answers, reference):
+        np.testing.assert_array_equal(got, want)
+
+
+def test_fit_device_is_the_default_off_the_tpu_or_without_a_cpu_backend(
+        fit_device_cleared, monkeypatch):
+    """`_fit_device` is None (the default device) where the backend is the
+    CPU, and where the backend is a TPU but no CPU backend is present; the
+    fit then runs on the default device and `gp.host_fits` stays put."""
+    from repro.core import trace
+
+    gp = fit_device_cleared
+    assert gp._fit_device() is None
+    devices = jax.devices
+
+    def no_cpu(backend=None):
+        if backend == "cpu":
+            raise RuntimeError("Unknown backend cpu")
+        return devices(backend)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", no_cpu)
+    gp._fit_device.cache_clear()
+    assert gp._fit_device() is None
+    rng = np.random.default_rng(8)
+    Xs = [rng.normal(size=(n, 6)) for n in (4, 7)]
+    before = trace.counters_snapshot()
+    stack = GPStack(kind="linear").fit(Xs, [rng.normal(size=len(X))
+                                             for X in Xs])
+    assert not stack._params_on_host
+    assert trace.COUNTERS["gp.fits"] - before.get("gp.fits", 0) == 1
+    assert (trace.COUNTERS["gp.host_fits"]
+            - before.get("gp.host_fits", 0)) == 0
+    mu, _ = stack.posterior(rng.normal(size=(2, 3, 6)))
+    assert mu.shape == (2, 3) and np.isfinite(mu).all()
+
+
 def test_gp_classifier_stack_matches_individual():
     rng = np.random.default_rng(2)
     Xs = [rng.normal(size=(n, 3)) for n in (18, 30)]
