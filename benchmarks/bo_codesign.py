@@ -3,13 +3,6 @@
 Reports per-model EDP improvement over the hand-designed accelerator (Eyeriss
 + heuristic random mapper, Timeloop-style), the paper's headline table
 (18.3% / 40.2% / 21.8% / 16.0% for ResNet / DQN / MLP / Transformer).
-
-Also benchmarks the batched evaluation engine (`repro.timeloop.batch`) against
-the scalar reference path on the co-design hot loop — per-trial candidate-pool
-sampling + featurization + EDP scoring — and end-to-end on a reduced nested
-co-design run.  `run(..., collect=dict)` fills a JSON-serializable record
-(wall time, best log10 EDP per seed, speedups) that `benchmarks/run.py --json`
-writes to BENCH_codesign.json so the perf trajectory is tracked across PRs.
 """
 
 from __future__ import annotations
@@ -21,35 +14,23 @@ import numpy as np
 
 from repro.core import (CodesignConfig, CodesignEngine, EngineConfig,
                         HWSearchConfig, SWSearchConfig, optimize_software)
-from repro.core.bo import BOResult
 from repro.core.hwspace import HardwareSpace
-from repro.core.swspace import SoftwareSpace
 from repro.core.baselines import random_search
-from repro.timeloop import MODEL_LAYERS, eyeriss_baseline_edp, eyeriss_168
-from repro.timeloop import batch as tlb
-from repro.timeloop import evaluate
-from repro.timeloop.mapping import constrained_random_mapping, mapping_is_valid
+from repro.timeloop import MODEL_LAYERS, eyeriss_baseline_edp
 
 
 def bench_config(model: str, n_hw: int, n_sw: int, seed: int = 0,
                  backend: str | None = None, gp_refit_every: int = 1,
-                 batched: bool = True, strategy: str = "auto",
-                 hw_warmup: int | None = None, spec_k: int = 4,
-                 hw_gp_refit_every: int = 1) -> CodesignConfig:
+                 batched: bool = True) -> CodesignConfig:
     """The benchmark suite's reduced-budget `CodesignConfig` (pool 60, warmup
-    n_sw//3 capped at 20 -- the pre-config kwarg bundle, as one object)."""
+    n_sw//3 capped at 20)."""
     num_pes = 256 if model == "transformer" else 168
     return CodesignConfig(
         sw=SWSearchConfig(n_trials=n_sw, n_warmup=min(20, n_sw // 3),
                           pool_size=60),
-        hw=HWSearchConfig(n_trials=n_hw, pool_size=60, num_pes=num_pes,
-                          spec_k=spec_k,
-                          **({} if hw_warmup is None
-                             else {"n_warmup": hw_warmup})),
-        engine=EngineConfig(backend=backend, strategy=strategy,
-                            gp_refit_every=gp_refit_every, batched=batched,
-                            use_cache=batched,
-                            hw_gp_refit_every=hw_gp_refit_every),
+        hw=HWSearchConfig(n_trials=n_hw, pool_size=60, num_pes=num_pes),
+        engine=EngineConfig(backend=backend, gp_refit_every=gp_refit_every,
+                            batched=batched, use_cache=batched),
         seed=seed,
     )
 
@@ -122,684 +103,9 @@ def run_model(model: str, n_hw: int = 12, n_sw: int = 60, seeds=(0,),
     }
 
 
-def engine_speedup(layers=("ResNet-K2", "DQN-K1", "Transformer-K2"),
-                   pool: int = 150, reps: int = 20, seed: int = 0) -> dict:
-    """Hot-path microbenchmark mirroring exactly one BO acquisition trial:
-    draw an input-valid pool, featurize it, evaluate the acquisition argmax
-    (here: candidate 0 — the surrogate posterior is engine-independent and
-    excluded).  Scalar reference vs the NumPy batch engine vs the JAX engine
-    (`batch_jax`, jit-warmed before timing), per layer plus geomeans — both
-    backends' hot-path timings land in BENCH_codesign.json."""
-    from repro.timeloop import PAPER_WORKLOADS
-    from repro.timeloop import batch_jax as jtlb
-
-    hw = eyeriss_168()
-    out: dict = {"pool": pool, "reps": reps, "layers": {}}
-    speedups, speedups_jax = [], []
-    for name in layers:
-        layer = PAPER_WORKLOADS[name]
-        space = SoftwareSpace(hw, layer)
-
-        rng = np.random.default_rng(seed)
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            cands = []
-            while len(cands) < pool:
-                m = constrained_random_mapping(rng, hw, layer)
-                if mapping_is_valid(m, hw, layer)[0]:
-                    cands.append(m)
-            np.stack([space.features(m) for m in cands])
-            evaluate(hw, cands[0], layer)
-        t_scalar = time.perf_counter() - t0
-
-        rng = np.random.default_rng(seed)
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            mb = tlb.sample_valid_pool(rng, hw, layer, pool)
-            tlb.features_batch(mb, hw, layer)
-            evaluate(hw, mb[0], layer)
-        t_batched = time.perf_counter() - t0
-
-        # JAX engine: the fused device program covers features + EDP in one
-        # dispatch; warm the jit cache outside the timed region.
-        rng = np.random.default_rng(seed)
-        warm = tlb.sample_valid_pool(rng, hw, layer, pool)
-        jtlb.forward_device(hw, warm, layer)["features"].block_until_ready()
-        rng = np.random.default_rng(seed)
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            mb = tlb.sample_valid_pool(rng, hw, layer, pool)
-            jtlb.forward_device(hw, mb, layer)["features"].block_until_ready()
-            evaluate(hw, mb[0], layer)  # same per-trial winner eval as above
-        t_jax = time.perf_counter() - t0
-
-        sp = t_scalar / t_batched
-        sp_jax = t_scalar / t_jax
-        speedups.append(sp)
-        speedups_jax.append(sp_jax)
-        out["layers"][name] = {
-            "scalar_s": round(t_scalar, 4),
-            "batched_s": round(t_batched, 4),
-            "jax_s": round(t_jax, 4),
-            "speedup": round(sp, 2),
-            "jax_speedup": round(sp_jax, 2),
-        }
-    out["geomean_speedup"] = round(float(np.exp(np.mean(np.log(speedups)))), 2)
-    out["geomean_jax_speedup"] = round(
-        float(np.exp(np.mean(np.log(speedups_jax)))), 2)
-    return out
-
-
-def e2e_speedup(model: str = "dqn", n_hw: int = 4, n_sw: int = 40,
-                seed: int = 0) -> dict:
-    """End-to-end nested co-design at reduced budgets: NumPy / JAX batch
-    engines + (hw, layer) cache vs the pre-engine scalar path.  (GP surrogate
-    fits are identical on all sides, so this is bounded well below the raw
-    engine speedup; the hot-path numbers are in `engine_speedup`.)"""
-    layers = MODEL_LAYERS[model]
-    out = {}
-    for engine in ("scalar", "batched", "jax"):
-        batched = engine != "scalar"
-        backend = "jax" if engine == "jax" else "numpy"
-        cfg = bench_config(model, n_hw, n_sw, seed=seed, backend=backend,
-                           batched=batched)
-        if engine == "jax":
-            # Untimed warmup at the same pool/bucket sizes so one-time jit
-            # compiles don't land inside the timed window (mirrors the
-            # block_until_ready warmup in engine_speedup).
-            CodesignEngine(dataclasses.replace(
-                cfg, hw=dataclasses.replace(cfg.hw, n_trials=1))).run(layers)
-        t0 = time.perf_counter()
-        CodesignEngine(cfg).run(layers)
-        out[f"{engine}_s"] = round(time.perf_counter() - t0, 3)
-    out["speedup"] = round(out["scalar_s"] / out["batched_s"], 2)
-    out["jax_speedup"] = round(out["scalar_s"] / out["jax_s"], 2)
-    return out
-
-
-def layer_batch_speedup(model: str = "resnet", n_hw: int = 4, n_sw: int = 60,
-                        seed: int = 0, reps: int = 2) -> dict:
-    """Layer-batched nested search vs the sequential-layer path (the PR-2
-    baseline), per backend, on one multi-layer workload set.
-
-    Both sides run the *same* search (same seeds, same per-layer RNG streams;
-    see tests/test_layer_batch.py for the parity pin) -- the comparison
-    isolates what the multi-run engine fuses: per-BO-round evaluation
-    dispatches, surrogate refits, and acquisition scoring.  Each configuration
-    is timed `reps` times interleaved and the per-side minimum is compared,
-    which drops transient machine noise (shared CI hardware) rather than
-    averaging it into the ratio.  JIT caches are warmed untimed."""
-    layers = MODEL_LAYERS[model]
-    out: dict = {"model": model, "n_hw": n_hw, "n_sw": n_sw, "reps": reps}
-    for backend in ("numpy", "jax"):
-        cfgs = {
-            strat: bench_config(model, n_hw, n_sw, seed=seed, backend=backend,
-                                strategy=strat)
-            for strat in ("sequential", "layer_batched")
-        }
-        for cfg in cfgs.values():  # warm jit caches / one-time imports
-            CodesignEngine(dataclasses.replace(
-                cfg, hw=dataclasses.replace(cfg.hw, n_trials=1))).run(layers)
-        times: dict[str, list[float]] = {s: [] for s in cfgs}
-        for _ in range(reps):
-            for strat, cfg in cfgs.items():
-                t0 = time.perf_counter()
-                CodesignEngine(cfg).run(layers)
-                times[strat].append(time.perf_counter() - t0)
-        seq_s, batch_s = min(times["sequential"]), min(times["layer_batched"])
-        out[f"{backend}_sequential_s"] = round(seq_s, 3)
-        out[f"{backend}_batched_s"] = round(batch_s, 3)
-        out[f"{backend}_speedup"] = round(seq_s / batch_s, 2)
-    return out
-
-
-def probe_fanout_speedup(model: str = "resnet", n_hw: int = 4, n_sw: int = 60,
-                         seed: int = 0, reps: int = 2) -> dict:
-    """Probe-fanout nested search vs the layer-batched path, per backend --
-    the ROADMAP "parallelize across hardware probes" capability the config
-    API unlocked.
-
-    The outer budget is all warmup (`hw.n_warmup = n_hw`), so every probe is
-    an independent work item: `strategy="probe_fanout"` runs all H probes'
-    H*L inner searches as ONE stacked `bo_maximize_many` (on jax each BO
-    round is a single (H*L*B,)-row fused device program + one stacked GP
-    fit), while `layer_batched` evaluates the probes one at a time (H
-    dispatch-chains of L-run programs).  Both sides run the same searches with
-    the same seeds -- parity is pinned in tests/test_config_api.py -- so the
-    ratio isolates the fan-out's dispatch/fit amortization.  Timing protocol
-    matches `layer_batch_speedup`: interleaved reps, per-side minimum, jit
-    caches warmed untimed at full fan-out width."""
-    layers = MODEL_LAYERS[model]
-    out: dict = {"model": model, "n_hw": n_hw, "n_sw": n_sw, "reps": reps}
-    for backend in ("numpy", "jax"):
-        cfgs = {
-            strat: bench_config(model, n_hw, n_sw, seed=seed, backend=backend,
-                                strategy=strat, hw_warmup=n_hw)
-            for strat in ("layer_batched", "probe_fanout")
-        }
-        for cfg in cfgs.values():
-            # Full untimed warm run per side: the fan-out's (H*L*bucket,)-row
-            # program and its stacked-GP bucket progression only exist at the
-            # real probe count and trial budget, so any reduced warmup would
-            # leave compiles inside the timed window.
-            CodesignEngine(cfg).run(layers)
-        times: dict[str, list[float]] = {s: [] for s in cfgs}
-        for _ in range(reps):
-            for strat, cfg in cfgs.items():
-                t0 = time.perf_counter()
-                CodesignEngine(cfg).run(layers)
-                times[strat].append(time.perf_counter() - t0)
-        base_s, fan_s = min(times["layer_batched"]), min(times["probe_fanout"])
-        out[f"{backend}_layer_batched_s"] = round(base_s, 3)
-        out[f"{backend}_fanout_s"] = round(fan_s, 3)
-        out[f"{backend}_speedup"] = round(base_s / fan_s, 2)
-    return out
-
-
-def speculative_speedup(model: str = "resnet", n_hw: int = 11, n_sw: int = 40,
-                        seed: int = 0, reps: int = 2, spec_k: int = 8,
-                        hw_gp_refit_every: int = 8,
-                        hw_warmup: int = 2) -> dict:
-    """Speculative scored-trial fan-out vs the probe_fanout path -- the
-    ROADMAP "parallelize the outer loop beyond warmup" capability.
-
-    Both sides run with the same outer refit stride (`hw_gp_refit_every`), so
-    the outer trajectory is identical (parity pinned in
-    tests/test_speculative.py) and the ratio isolates what speculation adds:
-    inside each frozen refit window, `speculative` evaluates the window's
-    whole q-batch (the top-`spec_k` acquisition candidates) as ONE stacked
-    k*L-run `bo_maximize_many` at the window's first trial, and the window's
-    remaining trials consume pure cache hits -- per window, one wide stacked
-    search replaces up to `stride` narrower ones.  `probe_fanout` evaluates
-    the same probes one scored trial at a time.  The budget is mostly scored
-    trials (`hw_warmup=2`) because that is the phase speculation covers; the
-    per-backend cache hit-rate lands in the record (the gate's health signal:
-    a silent 0 means speculation stopped predicting the outer loop).  Timing
-    protocol matches `layer_batch_speedup`: interleaved reps, per-side
-    minimum, jit caches warmed untimed by a full run."""
-    layers = MODEL_LAYERS[model]
-    out: dict = {"model": model, "n_hw": n_hw, "n_sw": n_sw, "reps": reps,
-                 "spec_k": spec_k, "hw_gp_refit_every": hw_gp_refit_every}
-    for backend in ("numpy", "jax"):
-        cfgs = {
-            strat: bench_config(model, n_hw, n_sw, seed=seed, backend=backend,
-                                strategy=strat, hw_warmup=hw_warmup,
-                                spec_k=spec_k,
-                                hw_gp_refit_every=hw_gp_refit_every)
-            for strat in ("probe_fanout", "speculative")
-        }
-        stats = {}
-        for strat, cfg in cfgs.items():  # warm jit caches at full width
-            stats[strat] = CodesignEngine(cfg).run(layers).stats
-        times: dict[str, list[float]] = {s: [] for s in cfgs}
-        for _ in range(reps):
-            for strat, cfg in cfgs.items():
-                t0 = time.perf_counter()
-                CodesignEngine(cfg).run(layers)
-                times[strat].append(time.perf_counter() - t0)
-        base_s, spec_s = min(times["probe_fanout"]), min(times["speculative"])
-        out[f"{backend}_probe_fanout_s"] = round(base_s, 3)
-        out[f"{backend}_speculative_s"] = round(spec_s, 3)
-        out[f"{backend}_speedup"] = round(base_s / spec_s, 2)
-        out[f"{backend}_hit_rate"] = round(
-            stats["speculative"]["spec_hit_rate"], 3)
-        out[f"{backend}_spec_evaluated"] = stats["speculative"]["spec_evaluated"]
-    return out
-
-
-def prune_speedup(models=(("dqn", 40), ("mlp", 100)), n_hw: int = 50,
-                  seed: int = 0, reps: int = 2, spec_k: int = 8,
-                  hw_gp_refit_every: int = 8, hw_warmup: int = 2) -> dict:
-    """Semi-decoupled bound gate (`prune="safe"`) vs `strategy="speculative"`
-    alone, at paper-scale outer budgets (n_hw=50) -- the ROADMAP
-    "semi-decoupled pruning" capability.
-
-    The gate skips the whole inner mapping search of any scored probe whose
-    provable EDP lower bound (`timeloop.bounds`) already exceeds the
-    incumbent's true model EDP, observing a censored bound-derived utility
-    instead; the incumbent is only updated by true evaluations, so the final
-    design is unaffected in the safe mode.  The savings scale with how often
-    the outer acquisition selects bound-dominated candidates (uninformed or
-    stale posteriors inside frozen refit windows), so the record carries
-    `*_probes_gated` -- the gate's health signal; a 0 means the bound never
-    vetoed a selection and the two sides did identical work.
-
-    Two records per workload and backend:
-
-      *_speedup      fixed-budget wall-clock ratio, off/safe (both sides run
-                     the identical trial budget; the safe side simply skips
-                     provably-wasted searches)
-      *_ttq_speedup  time-to-matched-quality ratio: time for each side to
-                     first reach the worse of the two finals (guards against
-                     a speedup bought with a quality loss)
-
-    Timing protocol matches `speculative_speedup`: interleaved reps,
-    per-side minimum, jit caches warmed untimed by one full run per side
-    (large outer budgets compile GP buckets the small warmups never touch)."""
-    out: dict = {"n_hw": n_hw, "reps": reps, "spec_k": spec_k,
-                 "hw_gp_refit_every": hw_gp_refit_every, "models": {}}
-
-    def traced(cfg, layers):
-        marks: list[tuple[float, float]] = []
-        t0 = time.perf_counter()
-        r = CodesignEngine(cfg).run(
-            layers, hw_callback=lambda t, res: marks.append(
-                (time.perf_counter() - t0, res.best_value)))
-        return r, marks, time.perf_counter() - t0
-
-    def time_to(marks, target):
-        for t, u in marks:
-            if u >= target:
-                return t
-        return float("inf")
-
-    for model, n_sw in models:
-        layers = MODEL_LAYERS[model]
-        rec: dict = {"n_sw": n_sw}
-        for backend in ("numpy", "jax"):
-            cfgs = {
-                mode: dataclasses.replace(
-                    base := bench_config(
-                        model, n_hw, n_sw, seed=seed, backend=backend,
-                        strategy="speculative", hw_warmup=hw_warmup,
-                        spec_k=spec_k, hw_gp_refit_every=hw_gp_refit_every),
-                    hw=dataclasses.replace(base.hw, prune=mode))
-                for mode in ("off", "safe")
-            }
-            stats = {}
-            for mode, cfg in cfgs.items():  # warm jit caches at full width
-                stats[mode] = CodesignEngine(cfg).run(layers).stats
-            times: dict[str, list[float]] = {m: [] for m in cfgs}
-            ttq: dict[str, list[tuple]] = {m: [] for m in cfgs}
-            finals: dict[str, float] = {}
-            for _ in range(reps):
-                for mode, cfg in cfgs.items():
-                    r, marks, total = traced(cfg, layers)
-                    times[mode].append(total)
-                    ttq[mode].append(marks)
-                    finals[mode] = r.hw_result.best_value
-            target = min(finals["off"], finals["safe"])
-            t_off = min(time_to(m, target) for m in ttq["off"])
-            t_safe = min(time_to(m, target) for m in ttq["safe"])
-            off_s, safe_s = min(times["off"]), min(times["safe"])
-            rec[f"{backend}_off_s"] = round(off_s, 3)
-            rec[f"{backend}_safe_s"] = round(safe_s, 3)
-            rec[f"{backend}_speedup"] = round(off_s / safe_s, 2)
-            rec[f"{backend}_ttq_speedup"] = (
-                round(t_off / t_safe, 2) if t_safe > 0 else None)
-            rec[f"{backend}_probes_gated"] = stats["safe"]["probes_gated"]
-            rec[f"{backend}_gated_fraction"] = round(
-                stats["safe"]["probes_gated"] / n_hw, 3)
-            rec[f"{backend}_pruned_fraction"] = round(
-                stats["safe"]["pruned_fraction"], 3)
-        out["models"][model] = rec
-    return out
-
-
-def service_speedup(models=("dqn", "mlp", "dqn", "mlp", "dqn", "mlp"),
-                    n_hw: int = 6, n_sw: int = 25, seed: int = 0,
-                    reps: int = 2) -> dict:
-    """Co-design-as-a-service throughput: N concurrent requests through the
-    `CodesignService` (cross-request stacked dispatch fusion) vs the same N
-    requests served one standalone `CodesignEngine.run` at a time -- the
-    ISSUE-7 "requests/min" capability.
-
-    Per-request results are bit-identical on both sides (parity asserted on
-    every run and recorded), so the ratio isolates what the service fuses:
-    each tick, every live session's pending inner searches run as ONE stacked
-    `bo_maximize_many` instead of N separate dispatch chains.  `n_sw=25`
-    keeps every stacked fit inside the Cholesky regime where fusion is exact.
-
-    A second, untimed-cold / timed-warm pass exercises the persistent design
-    store: the warm service run must perform ZERO inner searches (all (hw,
-    layer) results replay from disk) -- `*_warm_store_misses` is the health
-    signal and `*_warm_s` the replay latency.  Timing protocol matches
-    `layer_batch_speedup`: interleaved reps, per-side minimum, jit caches
-    warmed untimed by one full pass per side."""
-    import shutil
-    import tempfile
-
-    from repro.core.config import ServiceConfig
-    from repro.service import CodesignService, ServiceRequest
-
-    out: dict = {"requests": list(models), "n_hw": n_hw, "n_sw": n_sw,
-                 "reps": reps}
-    for backend in ("numpy", "jax"):
-        cfgs = [bench_config(model, n_hw, n_sw, seed=seed + i, backend=backend)
-                for i, model in enumerate(models)]
-
-        def sequential():
-            return [CodesignEngine(c).run(MODEL_LAYERS[m])
-                    for m, c in zip(models, cfgs)]
-
-        def service(store_dir=None):
-            svc = CodesignService(ServiceConfig(max_slots=len(models),
-                                                store_dir=store_dir))
-            rids = [svc.submit(ServiceRequest(layers=tuple(MODEL_LAYERS[m]),
-                                              config=c))
-                    for m, c in zip(models, cfgs)]
-            responses = svc.run()
-            return [responses[rid].result for rid in rids]
-
-        seq_results = sequential()  # warm jit caches / one-time imports
-        svc_results = service()
-        parity = all(
-            a.best_model_edp == b.best_model_edp and a.best_hw == b.best_hw
-            for a, b in zip(seq_results, svc_results))
-        times: dict[str, list[float]] = {"sequential": [], "service": []}
-        for _ in range(reps):
-            for name, fn in (("sequential", sequential),
-                             ("service", service)):
-                t0 = time.perf_counter()
-                fn()
-                times[name].append(time.perf_counter() - t0)
-        seq_s, svc_s = min(times["sequential"]), min(times["service"])
-        out[f"{backend}_sequential_s"] = round(seq_s, 3)
-        out[f"{backend}_service_s"] = round(svc_s, 3)
-        out[f"{backend}_speedup"] = round(seq_s / svc_s, 2)
-        out[f"{backend}_rpm"] = round(len(models) / svc_s * 60.0, 1)
-        out[f"{backend}_sequential_rpm"] = round(len(models) / seq_s * 60.0, 1)
-        out[f"{backend}_parity"] = parity
-
-        # warm-store replay: cold pass populates, warm pass must not search
-        store_dir = tempfile.mkdtemp(prefix="bench_design_store_")
-        try:
-            service(store_dir=store_dir)  # cold, untimed
-            t0 = time.perf_counter()
-            warm_results = service(store_dir=store_dir)
-            out[f"{backend}_warm_s"] = round(time.perf_counter() - t0, 3)
-            out[f"{backend}_warm_store_misses"] = sum(
-                r.stats["store_misses"] for r in warm_results)
-        finally:
-            shutil.rmtree(store_dir, ignore_errors=True)
-    return out
-
-
-def transfer_speedup(models=("mlp", "dqn", "mlp"), n_hw: int = 6,
-                     n_sw: int = 25, seed: int = 0, reps: int = 2) -> dict:
-    """Cross-run transfer: a repeated/near-identical request sequence served
-    cold (no store, no history) vs served against a warmed design store +
-    trial history with `hw.warm_start` on -- the ISSUE-10 capability.
-
-    The warmed side replays every (hw, layer) search the cold pass already
-    paid for from the store (warmup probes draw the same RNG stream, so they
-    hit exactly), seeds its outer GP/classifier with the recorded trial
-    history, and serves approximate (nearest stored hardware) warm starts on
-    exact-key misses.  Contracts, per run:
-
-      parity       (asserted) the untimed setup pass (store + history
-                   attached, warm start OFF) is bit-identical to the cold
-                   results -- logging and persistence alone change nothing;
-      never_worse  (recorded) whether every warm-started request's final
-                   model EDP is <= its cold counterpart's.  Priors reshape
-                   the outer acquisition, and BO carries no per-seed
-                   monotonicity guarantee at small budgets, so this is data,
-                   not an invariant -- `tests/test_transfer.py` pins seeds
-                   where it holds;
-      the >=1.15x e2e bar (asserted, numpy): warm wall-clock vs cold -- or,
-                   when a machine's I/O noise eats the ratio, a never-worse
-                   run with a strictly better incumbent at the same budget
-                   (`*_improved`) keeps the record honest.
-
-    Timing protocol matches `layer_batch_speedup`: interleaved reps,
-    per-side minimum, jit caches warmed untimed."""
-    import shutil
-    import tempfile
-
-    from repro.core.config import ServiceConfig
-    from repro.service import CodesignService, ServiceRequest
-
-    out: dict = {"requests": list(models), "n_hw": n_hw, "n_sw": n_sw,
-                 "reps": reps}
-    for backend in ("numpy", "jax"):
-        cold_cfgs = [bench_config(m, n_hw, n_sw, seed=seed + i,
-                                  backend=backend)
-                     for i, m in enumerate(models)]
-        warm_cfgs = [dataclasses.replace(
-                         c, hw=dataclasses.replace(c.hw, warm_start=True))
-                     for c in cold_cfgs]
-
-        def serve(cfgs, store_dir=None, history_dir=None):
-            svc = CodesignService(ServiceConfig(max_slots=len(models),
-                                                store_dir=store_dir,
-                                                history_dir=history_dir))
-            rids = [svc.submit(ServiceRequest(layers=tuple(MODEL_LAYERS[m]),
-                                              config=c))
-                    for m, c in zip(models, cfgs)]
-            responses = svc.run()
-            return [responses[rid].result for rid in rids]
-
-        tmp = tempfile.mkdtemp(prefix="bench_transfer_")
-        store_dir, hist_dir = tmp + "/store", tmp + "/history"
-        try:
-            cold_results = serve(cold_cfgs)  # warm jit caches, untimed
-            # setup pass: populates store + history; with warm_start OFF it
-            # must be bit-identical to cold (the exactness contract of the
-            # persistence layer).
-            setup_results = serve(cold_cfgs, store_dir, hist_dir)
-            parity = all(
-                a.best_model_edp == b.best_model_edp and a.best_hw == b.best_hw
-                for a, b in zip(cold_results, setup_results))
-            assert parity, "store/history attachment changed a cold result"
-            warm_results = serve(warm_cfgs, store_dir, hist_dir)  # untimed
-            never_worse = all(
-                w.best_model_edp <= c.best_model_edp
-                for w, c in zip(warm_results, cold_results))
-            times: dict[str, list[float]] = {"cold": [], "warm": []}
-            for _ in range(reps):
-                for name, fn in (
-                        ("cold", lambda: serve(cold_cfgs)),
-                        ("warm", lambda: serve(warm_cfgs, store_dir,
-                                               hist_dir))):
-                    t0 = time.perf_counter()
-                    fn()
-                    times[name].append(time.perf_counter() - t0)
-        finally:
-            shutil.rmtree(tmp, ignore_errors=True)
-        cold_s, warm_s = min(times["cold"]), min(times["warm"])
-        out[f"{backend}_cold_s"] = round(cold_s, 3)
-        out[f"{backend}_warm_s"] = round(warm_s, 3)
-        out[f"{backend}_speedup"] = round(cold_s / warm_s, 2)
-        out[f"{backend}_parity"] = parity
-        out[f"{backend}_never_worse"] = never_worse
-        out[f"{backend}_improved"] = sum(
-            1 for w, c in zip(warm_results, cold_results)
-            if w.best_model_edp < c.best_model_edp)
-        out[f"{backend}_store_hits"] = sum(
-            r.stats["store_hits"] for r in warm_results)
-        out[f"{backend}_warm_hits"] = sum(
-            r.stats["warm_hits"] for r in warm_results)
-        out[f"{backend}_prior_rows"] = sum(
-            r.stats["prior_rows"] for r in warm_results)
-        if backend == "numpy":
-            # the gated acceptance bar: a real e2e win, in time or quality
-            assert out["numpy_speedup"] >= 1.15 or (
-                never_worse and out["numpy_improved"] > 0), (
-                f"transfer gave neither a >=1.15x e2e speedup "
-                f"({out['numpy_speedup']}x) nor a better incumbent")
-    return out
-
-
-def executor_speedup(models=("dqn", "mlp", "dqn", "mlp", "dqn", "mlp"),
-                     n_hw: int = 6, n_sw: int = 25, seed: int = 0,
-                     reps: int = 2, n_workers: int = 4) -> dict:
-    """Actor/learner fan-out: the 6-request mixed batch through a
-    process-executor service (`n_workers` spawn-started workers pulling the
-    per-tick fused dispatches, ticks overlapping) vs the same batch through
-    the single-process inline-executor service -- `service_e2e`'s timed
-    configuration.  Per-request results are bit-identical (parity asserted
-    and recorded), so the ratio isolates placement: the learner keeps every
-    outer GP/session state machine while workers run the stacked inner
-    searches on other cores.
-
-    The speedup scales with physical cores (`cpus` is recorded alongside:
-    on a single-core host the workers timeslice one core and the ratio
-    honestly sits at ~1x minus IPC overhead; at >= 4 cores the 4-worker
-    fan-out is where the >= 1.5-2x target lives).  Numpy backend -- the
-    gated configuration; worker pools start once, untimed, and persist
-    across reps like every other warm-cache protocol here."""
-    import os
-
-    from repro.core.config import ServiceConfig
-    from repro.parallel.executor import ProcessExecutor
-    from repro.service import CodesignService, ServiceRequest
-
-    cfgs = [bench_config(model, n_hw, n_sw, seed=seed + i, backend="numpy")
-            for i, model in enumerate(models)]
-
-    def serve(executor=None):
-        svc = CodesignService(ServiceConfig(max_slots=len(models)),
-                              executor=executor)
-        rids = [svc.submit(ServiceRequest(layers=tuple(MODEL_LAYERS[m]),
-                                          config=c))
-                for m, c in zip(models, cfgs)]
-        responses = svc.run()
-        return [responses[rid].result for rid in rids]
-
-    out: dict = {"requests": list(models), "n_hw": n_hw, "n_sw": n_sw,
-                 "reps": reps, "n_workers": n_workers,
-                 "cpus": os.cpu_count()}
-    pool = ProcessExecutor(n_workers=n_workers)
-    try:
-        single_results = serve()  # warm jit caches / one-time imports
-        pool_results = serve(pool)  # start + warm the worker pool, untimed
-        parity = all(
-            a.best_model_edp == b.best_model_edp and a.best_hw == b.best_hw
-            for a, b in zip(single_results, pool_results))
-        times: dict[str, list[float]] = {"single": [], "executor": []}
-        for _ in range(reps):
-            for name, fn in (("single", serve), ("executor",
-                                                 lambda: serve(pool))):
-                t0 = time.perf_counter()
-                fn()
-                times[name].append(time.perf_counter() - t0)
-    finally:
-        pool.close()
-    single_s, exec_s = min(times["single"]), min(times["executor"])
-    out["numpy_single_s"] = round(single_s, 3)
-    out["numpy_executor_s"] = round(exec_s, 3)
-    out["numpy_speedup"] = round(single_s / exec_s, 2)
-    out["numpy_rpm"] = round(len(models) / exec_s * 60.0, 1)
-    out["numpy_parity"] = parity
-    return out
-
-
-def portfolio_speedup(workloads=("smollm_360m", "qwen3_14b",
-                                 "moonshot_v1_16b_a3b"),
-                      n_hw: int = 4, n_sw: int = 25, seed: int = 0,
-                      reps: int = 2) -> dict:
-    """Portfolio co-design (one chip for a weighted workload mix) vs per-model
-    specialist searches, on zoo-generated workload sets.
-
-    Two results ship in one record.  (1) The specialist-vs-portfolio EDP
-    *table*: each specialist chip (tuned for one model) and the uniform
-    portfolio chip, scored on every member's workload (cross entries re-run
-    the stacked inner search on the foreign chip with its content-derived
-    seed).  `gap` condenses it: the geomean EDP penalty of running a
-    specialist chip on the OTHER models vs their own specialists -- the
-    cross-model generalization gap of "Rethinking Co-design" (2102.08619) --
-    next to the portfolio chip's penalty, which should be smaller.  (2) The
-    wall-clock ratio: M standalone specialist searches vs ONE portfolio
-    search over the union stack at the same budgets (outer-loop fan-in: M*L
-    layers share each trial's stacked dispatch and GP fit).  Timing protocol
-    as everywhere: interleaved reps, per-side minimum, warm pass untimed.
-    One-hot parity (`one_hot_parity`) re-runs the portfolio with weight 1 on
-    the first member only and asserts it reproduces that specialist's chip
-    exactly -- the bit-parity contract that pins the whole construction.
-    Numpy numbers gate in CI; jax annotates."""
-    from repro.core.nested import optimize_software_many
-    from repro.workloads import (PortfolioConfig, portfolio_codesign,
-                                 resolve_workload)
-
-    member_layers = {m: tuple(resolve_workload(m)) for m in workloads}
-    out: dict = {"workloads": list(workloads), "n_hw": n_hw, "n_sw": n_sw,
-                 "reps": reps}
-
-    def _total_edp(hw, layers, cfg) -> float:
-        """Best-mapping model EDP of `layers` on a fixed chip, searched with
-        the same content-derived seed the engine would use."""
-        eng = CodesignEngine(cfg)
-        results = optimize_software_many(hw, list(layers), cfg.sw,
-                                         seed=eng.probe_seed(hw),
-                                         engine=cfg.engine)
-        total = 0.0
-        for layer, r in zip(layers, results):
-            if r.best_point is None:
-                return float("inf")
-            total += evaluate(hw, r.best_point, layer).edp
-        return total
-
-    for backend in ("numpy", "jax"):
-        cfg = bench_config("zoo", n_hw, n_sw, seed=seed, backend=backend,
-                           hw_warmup=2)
-
-        def specialists():
-            return {m: CodesignEngine(cfg).run(list(member_layers[m]))
-                    for m in workloads}
-
-        def portfolio():
-            return portfolio_codesign(PortfolioConfig(workloads=workloads),
-                                      cfg)
-
-        spec = specialists()  # warm jit caches / one-time imports, untimed
-        port = portfolio()
-        times: dict[str, list[float]] = {"specialists": [], "portfolio": []}
-        for _ in range(reps):
-            for name, fn in (("specialists", specialists),
-                             ("portfolio", portfolio)):
-                t0 = time.perf_counter()
-                fn()
-                times[name].append(time.perf_counter() - t0)
-        spec_s = min(times["specialists"])
-        port_s = min(times["portfolio"])
-        out[f"{backend}_specialists_s"] = round(spec_s, 3)
-        out[f"{backend}_portfolio_s"] = round(port_s, 3)
-        out[f"{backend}_speedup"] = round(spec_s / port_s, 2)
-
-        if backend != "numpy":
-            continue
-        # --- specialist-vs-portfolio EDP table (numpy, computed once) ------
-        port_edps = port.stats["portfolio_member_edps"]
-        table: dict[str, dict[str, float]] = {}
-        for m in workloads:
-            row = {}
-            for m2 in workloads:
-                row[m2] = (spec[m].best_model_edp if m2 == m else
-                           _total_edp(spec[m].best_hw, member_layers[m2],
-                                      cfg))
-            table[f"specialist:{m}"] = row
-        table["portfolio"] = {m2: port_edps[m2] for m2 in workloads}
-        out["table"] = {chip: {m: _finite(v) for m, v in row.items()}
-                        for chip, row in table.items()}
-
-        def geomean(ratios):
-            ratios = [r for r in ratios]
-            return float(np.exp(np.mean(np.log(ratios)))) if ratios else None
-
-        cross = [table[f"specialist:{m}"][m2] / table[f"specialist:{m2}"][m2]
-                 for m in workloads for m2 in workloads if m2 != m]
-        port_pen = [table["portfolio"][m2] / table[f"specialist:{m2}"][m2]
-                    for m2 in workloads]
-        out["gap"] = {
-            "specialist_cross_penalty": _finite(round(geomean(cross), 3)),
-            "portfolio_penalty": _finite(round(geomean(port_pen), 3)),
-        }
-        # --- one-hot parity: the acceptance-contract bit-parity check ------
-        hot = portfolio_codesign(
-            PortfolioConfig(workloads=workloads,
-                            weights=(1.0,) + (0.0,) * (len(workloads) - 1)),
-            cfg)
-        first = workloads[0]
-        out["one_hot_parity"] = bool(
-            hot.best_hw == spec[first].best_hw
-            and hot.stats["portfolio_member_edps"][first]
-            == spec[first].best_model_edp)
-    return out
-
-
 def run(n_hw: int = 12, n_sw: int = 60, seeds=(0,), quiet: bool = False,
-        collect: dict | None = None, backend: str | None = None,
-        gp_refit_every: int = 1, config: CodesignConfig | None = None):
+        backend: str | None = None, gp_refit_every: int = 1,
+        config: CodesignConfig | None = None):
     """Fig. 4/5a over the four seed models.  `config` (e.g. loaded from
     `benchmarks/run.py --config path.json`) overrides the per-model budget
     construction entirely -- only the seed is replaced per run."""
@@ -813,135 +119,7 @@ def run(n_hw: int = 12, n_sw: int = 60, seeds=(0,), quiet: bool = False,
                   f"codesign={r['codesign_edp']:.3e},"
                   f"improvement={r['improvement_pct']:.1f}%,"
                   f"time={sum(r['wall_time_s']):.1f}s")
-        if collect is not None:
-            collect.setdefault("codesign", {})[model] = {
-                "eyeriss_edp": _finite(r["eyeriss_edp"]),
-                "codesign_edp": _finite(r["codesign_edp"]),
-                "improvement_pct": _finite(round(r["improvement_pct"], 2)),
-                "wall_time_s": [round(t, 3) for t in r["wall_time_s"]],
-                "best_log10_edp_per_seed": [
-                    _finite(b) for b in r["best_log10_edp_per_seed"]
-                ],
-                "seeds": list(seeds),
-                "backend": r["backend"],
-            }
     return out
-
-
-def _finite(x: float):
-    """JSON-safe number: strict JSON has no Infinity/NaN token, so non-finite
-    values (e.g. a seed with no feasible design) become null."""
-    return float(x) if np.isfinite(x) else None
-
-
-def print_speedups(eng: dict, e2e: dict, lb: dict | None = None,
-                   pf: dict | None = None, spec: dict | None = None,
-                   prune: dict | None = None,
-                   svc: dict | None = None,
-                   execu: dict | None = None,
-                   portfolio: dict | None = None,
-                   transfer: dict | None = None) -> None:
-    """CSV lines for the engine/e2e speedup records (shared with run.py)."""
-    for name, r in eng["layers"].items():
-        print(f"engine,{name},scalar={r['scalar_s']}s,"
-              f"batched={r['batched_s']}s,jax={r['jax_s']}s,"
-              f"speedup={r['speedup']}x,jax_speedup={r['jax_speedup']}x")
-    print(f"engine,geomean,speedup={eng['geomean_speedup']}x,"
-          f"jax_speedup={eng['geomean_jax_speedup']}x")
-    print(f"e2e,codesign,scalar={e2e['scalar_s']}s,"
-          f"batched={e2e['batched_s']}s,jax={e2e['jax_s']}s,"
-          f"speedup={e2e['speedup']}x,jax_speedup={e2e['jax_speedup']}x")
-    if lb is not None:
-        print(f"layer_batch,{lb['model']},"
-              f"numpy_seq={lb['numpy_sequential_s']}s,"
-              f"numpy_batched={lb['numpy_batched_s']}s,"
-              f"numpy_speedup={lb['numpy_speedup']}x,"
-              f"jax_seq={lb['jax_sequential_s']}s,"
-              f"jax_batched={lb['jax_batched_s']}s,"
-              f"jax_speedup={lb['jax_speedup']}x")
-    if pf is not None:
-        print(f"probe_fanout,{pf['model']},"
-              f"numpy_base={pf['numpy_layer_batched_s']}s,"
-              f"numpy_fanout={pf['numpy_fanout_s']}s,"
-              f"numpy_speedup={pf['numpy_speedup']}x,"
-              f"jax_base={pf['jax_layer_batched_s']}s,"
-              f"jax_fanout={pf['jax_fanout_s']}s,"
-              f"jax_speedup={pf['jax_speedup']}x")
-    if spec is not None:
-        print(f"speculative,{spec['model']},"
-              f"numpy_base={spec['numpy_probe_fanout_s']}s,"
-              f"numpy_spec={spec['numpy_speculative_s']}s,"
-              f"numpy_speedup={spec['numpy_speedup']}x,"
-              f"numpy_hit_rate={spec['numpy_hit_rate']},"
-              f"jax_base={spec['jax_probe_fanout_s']}s,"
-              f"jax_spec={spec['jax_speculative_s']}s,"
-              f"jax_speedup={spec['jax_speedup']}x,"
-              f"jax_hit_rate={spec['jax_hit_rate']}")
-    if prune is not None:
-        for model, r in prune["models"].items():
-            print(f"prune,{model},"
-                  f"numpy_off={r['numpy_off_s']}s,"
-                  f"numpy_safe={r['numpy_safe_s']}s,"
-                  f"numpy_speedup={r['numpy_speedup']}x,"
-                  f"numpy_ttq_speedup={r['numpy_ttq_speedup']}x,"
-                  f"numpy_gated={r['numpy_probes_gated']},"
-                  f"jax_off={r['jax_off_s']}s,"
-                  f"jax_safe={r['jax_safe_s']}s,"
-                  f"jax_speedup={r['jax_speedup']}x,"
-                  f"jax_gated={r['jax_probes_gated']}")
-    if svc is not None:
-        print(f"service,{len(svc['requests'])}req,"
-              f"numpy_seq={svc['numpy_sequential_s']}s,"
-              f"numpy_service={svc['numpy_service_s']}s,"
-              f"numpy_speedup={svc['numpy_speedup']}x,"
-              f"numpy_rpm={svc['numpy_rpm']},"
-              f"numpy_parity={svc['numpy_parity']},"
-              f"numpy_warm={svc['numpy_warm_s']}s,"
-              f"numpy_warm_misses={svc['numpy_warm_store_misses']},"
-              f"jax_seq={svc['jax_sequential_s']}s,"
-              f"jax_service={svc['jax_service_s']}s,"
-              f"jax_speedup={svc['jax_speedup']}x,"
-              f"jax_rpm={svc['jax_rpm']},"
-              f"jax_parity={svc['jax_parity']}")
-    if execu is not None:
-        print(f"executor,{len(execu['requests'])}req,"
-              f"workers={execu['n_workers']},cpus={execu['cpus']},"
-              f"numpy_single={execu['numpy_single_s']}s,"
-              f"numpy_executor={execu['numpy_executor_s']}s,"
-              f"numpy_speedup={execu['numpy_speedup']}x,"
-              f"numpy_rpm={execu['numpy_rpm']},"
-              f"numpy_parity={execu['numpy_parity']}")
-    if portfolio is not None:
-        print(f"portfolio,{len(portfolio['workloads'])}models,"
-              f"numpy_specialists={portfolio['numpy_specialists_s']}s,"
-              f"numpy_portfolio={portfolio['numpy_portfolio_s']}s,"
-              f"numpy_speedup={portfolio['numpy_speedup']}x,"
-              f"one_hot_parity={portfolio['one_hot_parity']},"
-              f"spec_cross_penalty="
-              f"{portfolio['gap']['specialist_cross_penalty']},"
-              f"portfolio_penalty={portfolio['gap']['portfolio_penalty']},"
-              f"jax_specialists={portfolio['jax_specialists_s']}s,"
-              f"jax_portfolio={portfolio['jax_portfolio_s']}s,"
-              f"jax_speedup={portfolio['jax_speedup']}x")
-        for chip, row in portfolio["table"].items():
-            cells = ",".join(f"{m}={v:.3e}" if v is not None else f"{m}=inf"
-                             for m, v in row.items())
-            print(f"portfolio_table,{chip},{cells}")
-    if transfer is not None:
-        print(f"transfer,{len(transfer['requests'])}req,"
-              f"numpy_cold={transfer['numpy_cold_s']}s,"
-              f"numpy_warm={transfer['numpy_warm_s']}s,"
-              f"numpy_speedup={transfer['numpy_speedup']}x,"
-              f"numpy_parity={transfer['numpy_parity']},"
-              f"numpy_never_worse={transfer['numpy_never_worse']},"
-              f"numpy_improved={transfer['numpy_improved']},"
-              f"numpy_store_hits={transfer['numpy_store_hits']},"
-              f"numpy_warm_hits={transfer['numpy_warm_hits']},"
-              f"numpy_prior_rows={transfer['numpy_prior_rows']},"
-              f"jax_cold={transfer['jax_cold_s']}s,"
-              f"jax_warm={transfer['jax_warm_s']}s,"
-              f"jax_speedup={transfer['jax_speedup']}x,"
-              f"jax_parity={transfer['jax_parity']}")
 
 
 if __name__ == "__main__":
@@ -950,25 +128,13 @@ if __name__ == "__main__":
     ap.add_argument("--paper", action="store_true",
                     help="paper-scale budgets (50 HW x 250 SW)")
     ap.add_argument("--hw-search", default="bo", choices=("bo", "random"))
-    ap.add_argument("--speedup", action="store_true",
-                    help="only run the batched-engine speedup benchmarks")
     ap.add_argument("--backend", default=None, choices=("numpy", "jax"),
                     help="inner evaluation engine for the co-design runs "
                          "(default: $REPRO_BACKEND or numpy)")
     ap.add_argument("--gp-refit-every", type=int, default=1,
                     help="inner-loop surrogate refit stride (GP amortization)")
     args = ap.parse_args()
-    if args.speedup:
-        # Reduced prune budgets here (the CI smoke's): the paper-scale
-        # defaults belong to benchmarks/run.py's recorded section.
-        print_speedups(engine_speedup(), e2e_speedup(), layer_batch_speedup(),
-                       probe_fanout_speedup(), speculative_speedup(),
-                       prune_speedup(models=(("dqn", 20), ("mlp", 25)),
-                                     n_hw=16, reps=1),
-                       service_speedup(reps=1),
-                       portfolio=portfolio_speedup(reps=1),
-                       transfer=transfer_speedup(reps=1))
-    elif args.paper:
+    if args.paper:
         run(n_hw=50, n_sw=250, seeds=(0, 1, 2), backend=args.backend,
             gp_refit_every=args.gp_refit_every)
     else:

@@ -2,7 +2,7 @@
 40.2% EDP improvement over Eyeriss), on the typed config API.
 
     PYTHONPATH=src python examples/codesign_dqn.py [--paper | --tiny]
-        [--strategy auto|sequential|layer_batched|probe_fanout|speculative]
+        [--strategy auto|sequential|speculative]
         [--hw-refit-every N] [--prune off|safe|aggressive]
         [--backend numpy|jax] [--save-config cfg.json]
 

@@ -3,15 +3,14 @@ hardware/software co-design, plus the beyond-paper TPU sharding autotuner.
 
 The search surface is the typed config API (`repro.core.config`):
 `CodesignConfig` (sw/hw/engine sections, JSON round-trip) run by a
-`CodesignEngine`; `codesign(**legacy_kwargs)` remains as a deprecation shim.
+`CodesignEngine`.
 """
 
 from repro.core.config import (ACQUISITIONS, BACKENDS, EXECUTOR_KINDS,
                                PALLAS_MODES, PRUNE_MODES, STRATEGIES,
                                SURROGATES, CodesignConfig, EngineConfig,
                                ExecutorConfig, HWSearchConfig, SearchConfig,
-                               ServiceConfig, SWSearchConfig,
-                               config_from_legacy_kwargs)
+                               ServiceConfig, SWSearchConfig)
 from repro.core.cache import LRUCache, SlotCache
 from repro.core.trace import counters_snapshot
 from repro.core.gp import GP, GPClassifier, GPClassifierStack, GPStack
@@ -20,12 +19,8 @@ from repro.core.bo import (BOLoop, BOResult, FanoutSearchSpec, bo_maximize,
                            bo_maximize_many, score_topk)
 from repro.core.swspace import LayerStackSpace, SoftwareSpace, fanout_spaces
 from repro.core.hwspace import HardwareSpace
-from repro.core.nested import (PROBE_STRATEGIES, CoDesignResult,
-                               CodesignEngine, LayerBatchedProbes,
-                               ProbeFanoutProbes, ProbeStrategy,
-                               SearchSession, SequentialProbes,
-                               SpeculativeProbes, codesign, optimize_software,
-                               optimize_software_fanout,
+from repro.core.nested import (CoDesignResult, CodesignEngine, SearchSession,
+                               optimize_software, optimize_software_fanout,
                                optimize_software_many)
 from repro.core.baselines import random_search, relax_round_bo, tvm_style_search
 from repro.core.trees import GradientBoostedTrees, RandomForestSurrogate
@@ -45,7 +40,6 @@ __all__ = [
     "SearchConfig",
     "ServiceConfig",
     "SWSearchConfig",
-    "config_from_legacy_kwargs",
     "LRUCache",
     "SlotCache",
     "counters_snapshot",
@@ -66,16 +60,9 @@ __all__ = [
     "SoftwareSpace",
     "fanout_spaces",
     "HardwareSpace",
-    "PROBE_STRATEGIES",
     "CoDesignResult",
     "CodesignEngine",
     "SearchSession",
-    "LayerBatchedProbes",
-    "ProbeFanoutProbes",
-    "ProbeStrategy",
-    "SequentialProbes",
-    "SpeculativeProbes",
-    "codesign",
     "optimize_software",
     "optimize_software_fanout",
     "optimize_software_many",

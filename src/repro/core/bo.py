@@ -123,7 +123,7 @@ def _prefetch_topk(space, pool, utility, k_cap: int | None = None) -> None:
     """Speculative-prefetch hook: spaces exposing `prefetch_topk_fn` (+ a
     `prefetch_topk` width > 1) get the trial's pool candidates ranked by
     acquisition utility, best first, BEFORE the argmax is evaluated.  The
-    nested driver's "speculative" strategy injects it on the hardware space to
+    nested driver's "speculative" path injects it on the hardware space to
     fan the top-k probes' inner searches out as one stacked multi-run program;
     entry 0 is the trial's own argmax, the rest are speculation.  Purely an
     observer: no RNG is consumed and the trial's own selection is untouched,

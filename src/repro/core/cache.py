@@ -1,6 +1,6 @@
 """Bounded caches for long-lived engine/service processes.
 
-A one-shot `codesign()` run can afford unbounded memoization, but the
+A one-shot `CodesignEngine.run` can afford unbounded memoization, but the
 co-design service (`repro.service`) keeps engines and spaces alive across
 many requests, so every cache in the hot path is bounded here and counts its
 traffic:

@@ -1,9 +1,8 @@
 """Typed, serializable search configuration for the co-design stack.
 
-The nested search used to thread ~19 positional kwargs through
-`codesign` -> `optimize_software(_many)` -> `bo_maximize(_many)` ->
-`SoftwareSpace`; every new capability meant another knob at every layer.  This
-module replaces that kwarg pipeline with a small set of frozen dataclasses:
+The nested search is configured by a small set of frozen dataclasses, not by
+keyword arguments threaded through `optimize_software(_many)` ->
+`bo_maximize(_many)` -> `SoftwareSpace`:
 
   `SearchConfig`      one BO loop's budget + acquisition + surrogate
     `SWSearchConfig`    inner (software-mapping) defaults: 250 trials / 30 warmup
@@ -18,10 +17,6 @@ Every enumerated string (backend / surrogate / acquisition / probe strategy /
 Pallas mode) is validated HERE, at construction, through one shared
 `validate_choice` site -- a bad value raises `ValueError` before any search
 starts instead of threading silently to a deep call site.
-
-`config_from_legacy_kwargs` maps the pre-config `codesign(**kwargs)` surface
-onto a `CodesignConfig` (the deprecation shim in `repro.core.nested` uses it);
-the old-kwarg -> config-field table lives in the README's "Search API" section.
 """
 
 from __future__ import annotations
@@ -34,8 +29,7 @@ from typing import Any
 BACKENDS = ("numpy", "jax")
 SURROGATES = ("gp_linear", "gp_se", "rf")
 ACQUISITIONS = ("lcb", "ei")
-STRATEGIES = ("auto", "sequential", "layer_batched", "probe_fanout",
-              "speculative")
+STRATEGIES = ("auto", "sequential", "speculative")
 PALLAS_MODES = ("jnp", "pallas", "interpret")
 PRUNE_MODES = ("off", "safe", "aggressive")
 EXECUTOR_KINDS = ("inline", "process")
@@ -101,7 +95,8 @@ class HWSearchConfig(SearchConfig):
     spec_k: fan-out width of the `strategy="speculative"` outer loop -- at each
     scored trial the top-k acquisition candidates are evaluated as one stacked
     multi-run program (the argmax feeds the BO history; the k-1 speculative
-    results prefill the (hw, layer) cache).  Ignored by other strategies.
+    results prefill the (hw, layer) cache); 1 fans out the warmup only.
+    Ignored by "sequential".
 
     prune: the semi-decoupled bound-and-prune pass (`timeloop.bounds`).  A
     scored probe whose summed per-layer EDP *lower bound* already exceeds the
@@ -230,17 +225,16 @@ class EngineConfig:
     """Evaluation machinery, orthogonal to either loop's search budget.
 
     backend         "numpy" | "jax" | None (None -> $REPRO_BACKEND or "numpy")
-    strategy        probe-evaluation strategy for the nested driver:
-                      "sequential"    L per-layer searches per hardware probe
-                      "layer_batched" one lockstep `bo_maximize_many` per probe
-                      "probe_fanout"  layer_batched + the outer warmup's H
-                                      independent probes fanned out as ONE
-                                      H*L-run stacked `bo_maximize_many`
-                      "speculative"   probe_fanout + per scored outer trial the
-                                      top-`hw.spec_k` acquisition candidates
-                                      fan out as one k*L-run stacked program
-                                      (argmax consumed, the rest cached)
-                      "auto"          layer_batched on jax, sequential on numpy
+    strategy        probe path of the nested driver:
+                      "sequential"   L per-layer searches per hardware probe
+                      "speculative"  one stacked search per probe, the outer
+                                     warmup's H probes as ONE H*L-run stacked
+                                     search, and per scored trial the
+                                     top-`hw.spec_k` acquisition candidates
+                                     as one k*L-run stacked search (argmax
+                                     consumed, the rest cached)
+                      "auto"         speculative when batched, cached and on
+                                     jax; sequential otherwise
     gp_refit_every  inner-loop surrogate refit stride (amortization)
     hw_gp_refit_every
                     OUTER-loop surrogate refit stride.  Trials inside one
@@ -301,7 +295,7 @@ class EngineConfig:
         _validate_positive_int("gp_refit_every", self.gp_refit_every)
         _validate_positive_int("hw_gp_refit_every", self.hw_gp_refit_every)
         _validate_positive_int("cache_entries", self.cache_entries, minimum=0)
-        if self.strategy in ("probe_fanout", "speculative") and not self.use_cache:
+        if self.strategy == "speculative" and not self.use_cache:
             raise ValueError(
                 f"strategy={self.strategy!r} requires use_cache=True: the "
                 "fan-out prefills the (hw, layer) cache that probe evaluation "
@@ -321,8 +315,8 @@ class EngineConfig:
         """Concrete strategy name ('auto' resolved against the backend)."""
         if self.strategy != "auto":
             return self.strategy
-        if self.batched and self.resolve_backend() == "jax":
-            return "layer_batched"
+        if self.batched and self.use_cache and self.resolve_backend() == "jax":
+            return "speculative"
         return "sequential"
 
 
@@ -439,56 +433,3 @@ class ServiceConfig:
         except TypeError as e:
             raise ValueError(f"invalid ServiceConfig dict: {e}") from None
 
-
-# --- legacy kwarg surface --------------------------------------------------------
-
-# old codesign kwarg -> (section, config field); None section = CodesignConfig
-# top level.  This is the migration table (also rendered in the README).
-LEGACY_KWARG_MAP: dict[str, tuple[str | None, str]] = {
-    "num_pes": ("hw", "num_pes"),
-    "n_hw_trials": ("hw", "n_trials"),
-    "n_hw_warmup": ("hw", "n_warmup"),
-    "hw_pool": ("hw", "pool_size"),
-    "n_sw_trials": ("sw", "n_trials"),
-    "n_sw_warmup": ("sw", "n_warmup"),
-    "sw_pool": ("sw", "pool_size"),
-    "backend": ("engine", "backend"),
-    "batched": ("engine", "batched"),
-    "use_cache": ("engine", "use_cache"),
-    "gp_refit_every": ("engine", "gp_refit_every"),
-    "seed": (None, "seed"),
-    "verbose": (None, "verbose"),
-    # acquisition / lam / surrogate applied to BOTH loops (the legacy API had
-    # one knob); layer_batched maps onto engine.strategy (see below).
-}
-_SHARED_SEARCH_KEYS = ("acquisition", "lam", "surrogate")
-
-
-def config_from_legacy_kwargs(**kw) -> CodesignConfig:
-    """Map the pre-config `codesign(**kwargs)` surface to a `CodesignConfig`.
-
-    `layer_batched` (bool | None) becomes `engine.strategy`:
-    None -> "auto", True -> "layer_batched", False -> "sequential"."""
-    sections: dict[str, dict] = {"sw": {}, "hw": {}, "engine": {}, None: {}}
-    if "layer_batched" in kw:
-        lb = kw.pop("layer_batched")
-        sections["engine"]["strategy"] = (
-            "auto" if lb is None else "layer_batched" if lb else "sequential")
-    for key in _SHARED_SEARCH_KEYS:
-        if key in kw:
-            v = kw.pop(key)
-            sections["sw"][key] = v
-            sections["hw"][key] = v
-    for key, value in kw.items():
-        if key not in LEGACY_KWARG_MAP:
-            raise TypeError(
-                f"codesign() got an unexpected keyword argument {key!r}; "
-                f"valid legacy kwargs: {sorted(LEGACY_KWARG_MAP) + ['layer_batched', *_SHARED_SEARCH_KEYS]}")
-        section, field = LEGACY_KWARG_MAP[key]
-        sections[section][field] = value
-    return CodesignConfig(
-        sw=SWSearchConfig(**sections["sw"]),
-        hw=HWSearchConfig(**sections["hw"]),
-        engine=EngineConfig(**sections["engine"]),
-        **sections[None],
-    )

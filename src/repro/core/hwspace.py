@@ -51,14 +51,15 @@ class HardwareSpace:
     # evaluate_fn(hw) -> (utility | None, feasible); injected by the nested driver.
     evaluate_fn: Callable[[HardwareConfig], tuple[float | None, bool]] | None = None
     # prefetch_fn(pool): optional batch hook, called once with the whole pool
-    # before evaluate_batch's scalar loop.  The nested driver's probe-fanout
-    # strategy injects it to run ALL warmup probes' inner software searches as
-    # one stacked multi-run fan-out; the loop below then reads cache hits.
+    # before evaluate_batch's scalar loop.  The nested driver's warmup fan-out
+    # (`CodesignEngine.prefetch`) runs ALL warmup probes' inner software
+    # searches through it as one stacked multi-run search; the loop below then
+    # reads cache hits.
     prefetch_fn: Callable[[list[HardwareConfig]], None] | None = None
     # prefetch_topk_fn(cands): optional per-scored-trial hook -- the BO loop
     # hands it the pool's top-`prefetch_topk` candidates ranked by acquisition
     # utility (best first; entry 0 is the trial's own argmax) before the argmax
-    # is evaluated.  The nested driver's speculative strategy injects it to fan
+    # is evaluated.  The nested driver's speculative path injects it to fan
     # the k probes' inner searches out as ONE stacked multi-run program: the
     # argmax probe's layers become cache hits for this trial's evaluation, the
     # k-1 speculative probes' for whichever later trial selects them.
@@ -182,7 +183,7 @@ class HardwareSpace:
         """Scalar evaluation per config (each is a full inner software search;
         only the BO warmup calls this, on a handful of points).  When a
         `prefetch_fn` is injected, the whole pool is handed to it first --
-        the probe-fanout strategy fans the pool's inner searches out as one
+        the engine's warmup fan-out runs the pool's inner searches as one
         stacked multi-run program, and the loop below hits its cache."""
         if self.prefetch_fn is not None:
             self.prefetch_fn(list(pool))

@@ -9,43 +9,30 @@ discoverable mapping for some layer is an *unknown-constraint* violation.
 
 The search is configured by one typed, serializable `CodesignConfig`
 (`repro.core.config`) and driven by a `CodesignEngine`, which owns the
-(hw, layer) -> best-mapping cache, the inner-seed stream, and a pluggable
-*probe-evaluation strategy* (`PROBE_STRATEGIES`):
+(hw, layer) -> best-mapping cache, the inner-seed stream, and the probe path,
+chosen by `EngineConfig.strategy` ("auto" resolved from the backend):
 
-  "sequential"     L per-layer `optimize_software` searches per hardware probe
-  "layer_batched"  one lockstep `bo_maximize_many` call per probe: the L
-                   per-layer searches advance together, one fused device
-                   program + one stacked GP fit per BO round
-  "probe_fanout"   layer_batched per probe, PLUS the outer loop's H warmup
-                   probes -- independent work items -- fanned out as ONE
-                   H*L-run stacked `bo_maximize_many` (each run seeded exactly
-                   as its probe's sequential search would be, so results are
-                   identical; on the JAX backend every BO round is a single
-                   (H*L*B,)-row fused dispatch)
-  "speculative"    probe_fanout, PLUS speculative fan-out of the scored outer
-                   trials: each trial's top-`hw.spec_k` acquisition candidates
-                   are evaluated as ONE k*L-run stacked `bo_maximize_many`
-                   (the argmax feeds the outer history exactly as the
-                   sequential path would; the k-1 speculative results prefill
-                   the (hw, layer) cache so later trials that select them are
-                   free -- hit-rate reported in `CoDesignResult.stats`)
-  "auto"           layer_batched when the backend is "jax", else sequential
+  "sequential"   L per-layer `optimize_software` searches per hardware probe,
+                 stopping at the first layer with no feasible mapping
+  "speculative"  one lockstep `optimize_software_many` call per probe, the
+                 outer warmup's H probes fanned out as ONE H*L-run stacked
+                 search, and each scored trial's top-`hw.spec_k` acquisition
+                 candidates as ONE k*L-run stacked search (the argmax feeds
+                 the outer history; the rest prefill the cache, hit-rate in
+                 `CoDesignResult.stats`).  `spec_k = 1` fans out the warmup
+                 only.
 
 Probe seeds are *content-derived* (`CodesignEngine.probe_seed`: a stable hash
 of the run seed and the probe's fields), so a probe's inner search is the same
 no matter when -- or how speculatively -- it is evaluated; that is what makes
-every strategy above bit-identical to "sequential" (within the stacked GP's
+"speculative" bit-identical to "sequential" (within the stacked GP's
 Cholesky regime, see tests/test_speculative.py).
-
-`codesign(**legacy_kwargs)` remains as a thin deprecation shim with pinned
-result parity (tests/test_config_api.py).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import hashlib
-import warnings
 from typing import Callable, Sequence
 
 import numpy as np
@@ -55,8 +42,7 @@ from repro.core.bo import (BOLoop, BOResult, FanoutSearchSpec,
                            InfeasibleSpace, _resolve_search_config,
                            bo_maximize, bo_maximize_many, score_topk)
 from repro.core.cache import LRUCache
-from repro.core.config import (CodesignConfig, EngineConfig, SWSearchConfig,
-                               config_from_legacy_kwargs)
+from repro.core.config import CodesignConfig, EngineConfig, SWSearchConfig
 from repro.core.hwspace import HardwareSpace
 from repro.core.swspace import SoftwareSpace, fanout_spaces
 from repro.timeloop.arch import HardwareConfig, hw_from_tuple
@@ -73,8 +59,8 @@ class CoDesignResult:
     hw_result: BOResult
     layer_edps: dict[str, float]
     # Engine accounting for the run: speculative probes evaluated / consumed
-    # as cache hits and the resulting hit rate (all zero for non-speculative
-    # strategies), plus the bound-and-prune pass's candidates considered /
+    # as cache hits and the resulting hit rate (all zero under "sequential"
+    # or spec_k = 1), plus the bound-and-prune pass's candidates considered /
     # pruned and the resulting pruned fraction, and the scored probes whose
     # whole inner search was vetoed by the bound gate (`probes_gated`; all
     # zero with prune="off").
@@ -201,152 +187,11 @@ def optimize_software_fanout(
     )[:len(items)]
 
 
-# --- probe-evaluation strategies -------------------------------------------------
-
-
 def _cache_entry(hw: HardwareConfig, layer: ConvLayer,
                  r: BOResult) -> tuple[Mapping | None, float]:
     if r.best_point is None:
         return (None, float("inf"))
     return (r.best_point, evaluate(hw, r.best_point, layer).edp)
-
-
-class ProbeStrategy:
-    """How a `CodesignEngine` evaluates one hardware probe's inner searches.
-
-    `evaluate_probe` must fill `engine.cache` for the probe's layers (honoring
-    `use_cache`); `prefetch` optionally batches the inner searches of a whole
-    warmup pool ahead of the per-probe calls (the probe-fanout capability).
-    Register implementations in `PROBE_STRATEGIES`."""
-
-    name = "base"
-
-    def evaluate_probe(self, engine: "CodesignEngine", hw: HardwareConfig,
-                       seed: int) -> None:
-        raise NotImplementedError
-
-    def prefetch(self, engine: "CodesignEngine",
-                 pool: Sequence[HardwareConfig]) -> None:
-        """Called once with the outer warmup pool before its probes are
-        evaluated; default: nothing (probes evaluate one at a time)."""
-
-    def prefetch_topk(self, engine: "CodesignEngine",
-                      cands: Sequence[HardwareConfig]) -> None:
-        """Called per scored outer trial with the acquisition pool's top-k
-        candidates, best first (entry 0 is the argmax the trial consumes);
-        default: nothing (the speculative strategy overrides this)."""
-
-
-class SequentialProbes(ProbeStrategy):
-    """L sequential per-layer `optimize_software` searches per probe, stopping
-    at the first layer with no feasible mapping (the pre-engine behavior)."""
-
-    name = "sequential"
-
-    def evaluate_probe(self, engine, hw, seed):
-        cfg = engine.config
-        for layer in engine._layers:
-            key = (hw, layer)
-            if not cfg.engine.use_cache or key not in engine.cache:
-                r = optimize_software(hw, layer, cfg.sw, seed=seed,
-                                      engine=cfg.engine)
-                engine.cache[key] = _cache_entry(hw, layer, r)
-            if engine.cache[key][0] is None:
-                break  # unknown constraint: remaining layers never searched
-
-
-class LayerBatchedProbes(ProbeStrategy):
-    """One lockstep `bo_maximize_many` call per probe: every layer this probe
-    still needs advances in one multi-run search (each layer seeded exactly as
-    its sequential `optimize_software` call would be, so cached entries are
-    interchangeable between strategies)."""
-
-    name = "layer_batched"
-
-    def evaluate_probe(self, engine, hw, seed):
-        cfg = engine.config
-        todo = list(dict.fromkeys(
-            layer for layer in engine._layers
-            if not cfg.engine.use_cache or (hw, layer) not in engine.cache))
-        if not todo:
-            return
-        rs = optimize_software_many(hw, todo, cfg.sw, seed=seed,
-                                    engine=cfg.engine)
-        for layer, r in zip(todo, rs):
-            engine.cache[(hw, layer)] = _cache_entry(hw, layer, r)
-
-
-class ProbeFanoutProbes(LayerBatchedProbes):
-    """Layer-batched per-probe evaluation PLUS warmup fan-out: the outer
-    loop's H warmup probes are independent, so their H*L inner searches run as
-    ONE stacked `bo_maximize_many` (content-derived per-run seeds --
-    `CodesignEngine.probe_seed` -- make each run exactly the search eval_hw
-    would launch for its probe; duplicate probes are searched once, exactly as
-    the cache would serve them sequentially).  Requires `use_cache=True`
-    (validated at `EngineConfig` construction)."""
-
-    name = "probe_fanout"
-
-    def prefetch(self, engine, pool):
-        items, seeds, _ = engine.pending_items(pool)
-        if not items:
-            return
-        for (hw, layer), entry in zip(items, engine.fanout(items, seeds)):
-            engine.cache[(hw, layer)] = entry
-
-
-class SpeculativeProbes(ProbeFanoutProbes):
-    """Warmup fan-out (inherited) PLUS speculative scored trials: the outer BO
-    loop hands `prefetch_topk` each trial pool's top-`hw.spec_k` acquisition
-    candidates (best first), and ALL their pending (hw, layer) searches run as
-    ONE stacked k*L-run `bo_maximize_many`.  Entry 0 is the argmax the trial
-    itself consumes -- its searches are the trial's own work, just fanned;
-    entries 1..k-1 are speculation whose results prefill the cache for
-    whichever later trial selects them (hit-rate in `CodesignEngine.stats`).
-
-    Because probe seeds are content-derived, a speculative fill is
-    bit-identical to the search the sequential path would run whenever it
-    first evaluates that probe, so speculation can never change what the
-    outer loop finds -- only when the inner-search work happens (parity
-    pinned in tests/test_speculative.py).  Requires `use_cache=True`
-    (validated at `EngineConfig` construction)."""
-
-    name = "speculative"
-
-    def prefetch_topk(self, engine, cands):
-        items, seeds, speculated = engine.pending_items(
-            cands, mark_speculated=True)
-        if not items:
-            return
-        n_layers = len(dict.fromkeys(engine._layers))
-        entries = engine.fanout(
-            items, seeds,
-            # Bucketed fan-out width on jax: pad the stack to a whole number
-            # of probes so the per-round fused program compiles for at most
-            # spec_k distinct run counts as cached probes drop out of later
-            # trials' top-k, while padding (real redundant runs -- batched GP
-            # slices are not free) stays under one probe's worth.
-            pad_to=-(-len(items) // n_layers) * n_layers)
-        for (hw, layer), entry in zip(items, entries):
-            engine.cache[(hw, layer)] = entry
-        engine.stats["spec_evaluated"] += len(speculated)
-        engine._speculated.update(speculated)
-
-    def evaluate_probe(self, engine, hw, seed):
-        if hw in engine._speculated:
-            # First consumption of a speculative fill: the probe the outer
-            # loop selected was evaluated ahead of time -> whole inner search
-            # skipped (all its layers are cache hits below).
-            engine._speculated.discard(hw)
-            engine.stats["spec_hits"] += 1
-        super().evaluate_probe(engine, hw, seed)
-
-
-PROBE_STRATEGIES: dict[str, type[ProbeStrategy]] = {
-    cls.name: cls
-    for cls in (SequentialProbes, LayerBatchedProbes, ProbeFanoutProbes,
-                SpeculativeProbes)
-}
 
 
 # --- the engine ------------------------------------------------------------------
@@ -362,17 +207,17 @@ class CodesignEngine:
         pool repeats configs, and pool candidates collide across trials); both
         keys are frozen dataclasses, so a hit skips the whole inner search.
         The inner search is stochastic, so caching also makes repeated probes
-        of one hardware point consistent.  The cache is shared by all probe
-        strategies (same keys, same values) and persists across `run` calls.
+        of one hardware point consistent.  The cache is shared by both probe
+        paths (same keys, same values) and persists across `run` calls.
       * the probe-seed derivation: a probe's inner searches are seeded by
         `probe_seed(hw)` -- a stable content hash of (config.seed, the
         probe's fields) -- so the seed does not depend on WHEN the probe is
         evaluated.  That makes evaluation order a free variable: warmup
         fan-out, speculative prefetch, and the plain sequential walk all run
         the exact same search for any given probe.
-      * the probe-evaluation strategy, resolved from
-        `config.engine.strategy` against `PROBE_STRATEGIES`, and the
-        speculative accounting (`stats`: probes evaluated speculatively,
+      * the probe path (`evaluate_probe`, `prefetch`, `prefetch_topk`),
+        chosen by `strategy_name` ("auto" resolved against the backend), and
+        the speculative accounting (`stats`: probes evaluated speculatively,
         speculative cache hits; reset per `run`).
     """
 
@@ -381,7 +226,6 @@ class CodesignEngine:
         self.config = config if config is not None else CodesignConfig()
         self.backend = self.config.engine.resolve_backend()
         self.strategy_name = self.config.engine.resolve_strategy()
-        self.strategy = PROBE_STRATEGIES[self.strategy_name]()
         # LRU-bounded when `engine.cache_entries` > 0 (the service applies its
         # bound here); 0 keeps the historical unbounded dict behavior.
         self.cache: LRUCache = LRUCache(self.config.engine.cache_entries)
@@ -422,10 +266,87 @@ class CodesignEngine:
             self._executor = None
             self._owns_executor = False
 
+    def evaluate_probe(self, hw: HardwareConfig, seed: int) -> None:
+        """Fill `cache` for one probe's layers.  Under "sequential": one
+        `optimize_software` search per layer, stopping at the first layer
+        with no feasible mapping.  Otherwise: one lockstep
+        `optimize_software_many` call over the layers still uncached (each
+        seeded exactly as its sequential search would be, so cached entries
+        are interchangeable between the two paths), after counting the
+        first consumption of a speculative fill as a hit."""
+        cfg = self.config
+        if self.strategy_name == "sequential":
+            for layer in self._layers:
+                key = (hw, layer)
+                if not cfg.engine.use_cache or key not in self.cache:
+                    r = optimize_software(hw, layer, cfg.sw, seed=seed,
+                                          engine=cfg.engine)
+                    self.cache[key] = _cache_entry(hw, layer, r)
+                if self.cache[key][0] is None:
+                    break  # unknown constraint: remaining layers never searched
+            return
+        if hw in self._speculated:
+            # First consumption of a speculative fill: the probe the outer
+            # loop selected was evaluated ahead of time -> whole inner search
+            # skipped (all its layers are cache hits below).
+            self._speculated.discard(hw)
+            self.stats["spec_hits"] += 1
+        todo = list(dict.fromkeys(
+            layer for layer in self._layers if (hw, layer) not in self.cache))
+        if not todo:
+            return
+        rs = optimize_software_many(hw, todo, cfg.sw, seed=seed,
+                                    engine=cfg.engine)
+        for layer, r in zip(todo, rs):
+            self.cache[(hw, layer)] = _cache_entry(hw, layer, r)
+
+    def prefetch(self, pool: Sequence[HardwareConfig]) -> None:
+        """Warmup fan-out, called once with the outer warmup pool before its
+        probes are evaluated: the H probes are independent, so their H*L
+        inner searches run as ONE stacked search (content-derived seeds make
+        each run exactly the search `evaluate_probe` would launch; duplicate
+        probes are searched once, as the cache would serve them).  A no-op
+        under "sequential"."""
+        if self.strategy_name == "sequential":
+            return
+        items, seeds, _ = self.pending_items(pool)
+        if not items:
+            return
+        for (hw, layer), entry in zip(items, self.fanout(items, seeds)):
+            self.cache[(hw, layer)] = entry
+
+    def prefetch_topk(self, cands: Sequence[HardwareConfig]) -> None:
+        """Speculative fan-out, called per scored outer trial with the
+        acquisition pool's top-`hw.spec_k` candidates, best first: ALL their
+        pending (hw, layer) searches run as ONE stacked k*L-run search.
+        Entry 0 is the argmax the trial itself consumes; entries 1..k-1 are
+        speculation whose results prefill the cache for whichever later trial
+        selects them (hit-rate in `stats`).  A speculative fill is the search
+        the sequential path would run for that probe, so speculation moves
+        inner-search work earlier and never changes what the outer loop
+        finds (parity pinned in tests/test_speculative.py)."""
+        items, seeds, speculated = self.pending_items(
+            cands, mark_speculated=True)
+        if not items:
+            return
+        n_layers = len(dict.fromkeys(self._layers))
+        entries = self.fanout(
+            items, seeds,
+            # Bucketed fan-out width on jax: pad the stack to a whole number
+            # of probes so the per-round fused program compiles for at most
+            # spec_k distinct run counts as cached probes drop out of later
+            # trials' top-k, while padding (real redundant runs -- batched GP
+            # slices are not free) stays under one probe's worth.
+            pad_to=-(-len(items) // n_layers) * n_layers)
+        for (hw, layer), entry in zip(items, entries):
+            self.cache[(hw, layer)] = entry
+        self.stats["spec_evaluated"] += len(speculated)
+        self._speculated.update(speculated)
+
     def probe_seed(self, hw: HardwareConfig) -> int:
         """Content-derived inner-search seed for one hardware probe: a stable
         (process- and platform-independent) hash of the run seed and the
-        probe's field values.  Every strategy seeds a probe's inner searches
+        probe's field values.  Both probe paths seed a probe's inner searches
         through this, which is what lets speculative/fanned-out evaluation
         reproduce the sequential path bit-for-bit."""
         data = repr((self.config.seed, dataclasses.astuple(hw))).encode()
@@ -532,8 +453,8 @@ class CodesignEngine:
 
     def probe_doomed(self, hw: HardwareConfig) -> bool:
         """True when the bound gate would veto this probe's inner search --
-        fan-out strategies use it to keep provably-wasted searches out of
-        their stacked programs (the gate itself censors the probe if the
+        the fan-outs use it to keep provably-wasted searches out of their
+        stacked programs (the gate itself censors the probe if the
         outer loop ever consumes it)."""
         return self._gate is not None and self._gate(hw, count=False) is not None
 
@@ -545,8 +466,8 @@ class CodesignEngine:
         speculative-consumption accounting -- entry 0 of `cands` is the work
         its trial consumes itself).
 
-        This is THE unit of schedulable inner-search work: the fan-out
-        strategies stack a single trial's items into one multi-run program,
+        This is THE unit of schedulable inner-search work: the engine's
+        fan-outs stack a single trial's items into one multi-run program,
         and the co-design service (`repro.service`) stacks the items of many
         concurrent sessions' trials the same way -- content-derived seeds
         make both result-preserving."""
@@ -603,7 +524,7 @@ class SearchSession:
 
     Wraps the outer hardware `BOLoop` plus everything `CodesignEngine.run`
     used to hold in closures: the incumbent (`best`), the bound gate, the
-    probe-strategy hooks, and the per-run stats.  The outer-trial state --
+    probe-path hooks, and the per-run stats.  The outer-trial state --
     GP history, frozen pool window, elite carry-forward, prune gate -- is
     stepped one trial at a time (`step`), snapshotted (`snapshot`/`restore`),
     and interleaved with other sessions by the co-design service.
@@ -641,10 +562,9 @@ class SearchSession:
         self.space = HardwareSpace(
             num_pes=cfg.hw.num_pes,
             evaluate_fn=self._eval_hw,
-            prefetch_fn=lambda pool: engine.strategy.prefetch(engine, pool),
-            prefetch_topk_fn=(
-                (lambda cands: engine.strategy.prefetch_topk(engine, cands))
-                if self._spec_k > 1 else None),
+            prefetch_fn=engine.prefetch,
+            prefetch_topk_fn=(engine.prefetch_topk
+                              if self._spec_k > 1 else None),
             prefetch_topk=self._spec_k,
             prune_fn=engine._make_prune_fn(self.best),
         )
@@ -756,7 +676,7 @@ class SearchSession:
             censored = self.gate(hw)
             if censored is not None:
                 return censored, True  # bound veto: no inner search run
-        engine.strategy.evaluate_probe(engine, hw, engine.probe_seed(hw))
+        engine.evaluate_probe(hw, engine.probe_seed(hw))
         total_edp = 0.0
         maps: dict[str, Mapping] = {}
         per_layer: dict[str, float] = {}
@@ -796,11 +716,11 @@ class SearchSession:
         plan is cached until `step()` commits it, so calling this is
         trajectory-neutral.
 
-        Mirrors what each strategy would launch inline: the whole warmup
+        Mirrors what the probe path would launch inline: the whole warmup
         pool's probes, a pre-surrogate trial's sampled probe, or a scored
         trial's acquisition argmax -- widened to the top-`hw.spec_k`
         candidates (capped by the frozen window's remaining trials, exactly
-        like `_prefetch_topk`) under the speculative strategy.  Items are
+        like `_prefetch_topk`) under "speculative".  Items are
         filtered through `engine.pending_items`, so cached, duplicate, and
         bound-doomed probes drop out."""
         plan = self.loop.plan()
@@ -878,36 +798,3 @@ class SearchSession:
             self.engine.cache[key] = value
         return self
 
-
-def codesign(
-    layers: Sequence[ConvLayer],
-    config: CodesignConfig | None = None,
-    **legacy_kwargs,
-) -> CoDesignResult:
-    """Run the nested co-design search.
-
-    The supported surface is `codesign(layers, config=CodesignConfig(...))`
-    (or `CodesignEngine(config).run(layers)` to keep the cache across runs).
-    The pre-config kwargs (`n_hw_trials=...`, `sw_pool=...`,
-    `layer_batched=...`, ...) still work as a thin shim -- mapped through
-    `config_from_legacy_kwargs`, result parity pinned in
-    tests/test_config_api.py -- but emit a DeprecationWarning; the old-kwarg
-    -> config-field table is in the README's "Search API" section."""
-    if config is not None and not isinstance(config, CodesignConfig):
-        # Loud break for pre-config positional callers (num_pes used to be
-        # the second positional argument).
-        raise TypeError(
-            f"config must be a CodesignConfig, got {config!r}; legacy "
-            f"options must be passed by keyword (num_pes=...)")
-    if legacy_kwargs:
-        if config is not None:
-            raise TypeError(
-                "pass either config= or legacy keyword arguments, not both")
-        warnings.warn(
-            "codesign(**kwargs) is deprecated: build a CodesignConfig and "
-            "call codesign(layers, config=...) or "
-            "CodesignEngine(config).run(layers) (see the README 'Search API' "
-            "migration table)",
-            DeprecationWarning, stacklevel=2)
-        config = config_from_legacy_kwargs(**legacy_kwargs)
-    return CodesignEngine(config).run(layers)

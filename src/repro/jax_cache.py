@@ -3,8 +3,9 @@
 The search compiles one program per pool bucket, GP bucket and stack width,
 most of them in well under a second, and a cold process compiles them all
 again.  `enable_compile_cache()` turns the persistent cache on for every such
-program.  Entry points (`chip_smoke.py`, `benchmarks/run.py`, the examples)
-call it once, before their first compile; library code never does.
+program.  Entry points (`bench/run.py`, `chip_smoke.py`, `benchmarks/run.py`,
+the examples) call it once, before their first compile; library code never
+does.
 """
 
 from __future__ import annotations

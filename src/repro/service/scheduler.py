@@ -37,9 +37,8 @@ change it.  Two scope notes: cross-request stacking inherits the stacked GP's
 Cholesky-regime contract (see tests/test_layer_batch.py), and under
 `strategy="sequential"` with `hw.prune != "off"` the standalone path stops a
 probe's per-layer searches at the first infeasible layer while the service
-prefills all of them, which can shift WHEN the bound gate censors -- the
-batched strategies (layer_batched/probe_fanout/speculative) search all layers
-inline too and carry no such caveat.
+prefills all of them, which can shift WHEN the bound gate censors --
+"speculative" searches all layers inline too and carries no such caveat.
 """
 
 from __future__ import annotations
@@ -386,9 +385,9 @@ class CodesignService:
         # executor (inline: runs now; process: workers pull it while the
         # learner keeps ticking).  On the JAX backend every BO round of ALL
         # fused requests' searches is a single fused device program.  Pad to
-        # a whole number of probes (the speculative strategy's bucketing) so
-        # the compiled per-round width stays stable as sessions' per-tick
-        # item counts fluctuate.
+        # a whole number of probes (`CodesignEngine.prefetch_topk`'s
+        # bucketing) so the compiled per-round width stays stable as
+        # sessions' per-tick item counts fluctuate.
         for g in groups.values():
             cfg = g["slot"].engine.config
             spec = FanoutSearchSpec(

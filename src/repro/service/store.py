@@ -77,7 +77,7 @@ def design_key(hw: HardwareConfig, layer: ConvLayer,
     inner `bo_maximize` consumes (resolved backend, refit stride, batched
     protocol, pallas mode), and the probe's content-derived seed.  Engine
     fields that only move work around (strategy, use_cache, hw_*) are
-    excluded -- strategies are pinned bit-identical to sequential."""
+    excluded -- "speculative" is pinned bit-identical to "sequential"."""
     eng = (engine_cfg.resolve_backend(), engine_cfg.gp_refit_every,
            engine_cfg.batched, engine_cfg.pallas_mode)
     data = repr((dataclasses.astuple(hw), dataclasses.astuple(layer),

@@ -27,7 +27,7 @@ row* -- the rows of one batch may belong to different layers AND different
 hardware configs -- which is what lets `forward_device_stacked` pack candidate
 pools into a single stacked device program: all L layers of one hardware probe
 (the layer-batched nested search, (L*B,) rows), or all H*L (probe, layer)
-searches of the outer loop's warmup fan-out (`strategy="probe_fanout"`,
+searches of the outer loop's warmup fan-out (`strategy="speculative"`,
 (H*L*B,) rows).  Either way it is the *same* jitted `_forward` program as the
 single-layer path, so per-row results are identical.
 
@@ -38,7 +38,7 @@ the NumPy engine at ~1e-12.  On TPU the default is float32, which meets the
 computed in; the f64 ones are read on the host or inside another x64 scope.
 
 Backend selection from the search stack: `SoftwareSpace(..., backend="jax")`,
-`codesign(..., backend="jax")`, `benchmarks/run.py --backend jax`, or the
+`EngineConfig(backend="jax")`, `benchmarks/run.py --backend jax`, or the
 `REPRO_BACKEND=jax` environment variable (see README).
 """
 

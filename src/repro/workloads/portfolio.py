@@ -26,8 +26,8 @@ in tests/test_portfolio.py.
 
 Two engine-config restrictions, enforced loudly: `hw.prune` must be "off"
 (the EDP lower-bound gate is keyed on a summed-EDP incumbent, which has no
-meaning under the weighted objective), and the "sequential" probe strategy is
-upgraded to the bit-identical "layer_batched" (`make_portfolio_engine`) --
+meaning under the weighted objective), and the "sequential" probe path is
+upgraded to the bit-identical "speculative" (`make_portfolio_engine`) --
 sequential stops a probe at its first infeasible layer, which would leave
 later members' cache entries unevaluated and mis-attribute feasibility.
 """
@@ -117,7 +117,7 @@ class PortfolioSession(SearchSession):
                 "portfolio search cannot use the 'sequential' probe "
                 "strategy (it stops at the first infeasible layer, leaving "
                 "later members unevaluated); use make_portfolio_engine(), "
-                "which upgrades it to the bit-identical 'layer_batched'")
+                "which upgrades it to the bit-identical 'speculative'")
         self.portfolio = portfolio
         self._member_layers = tuple(
             tuple(resolve_workload(w)) for w in portfolio.workloads)
@@ -130,7 +130,7 @@ class PortfolioSession(SearchSession):
 
     def _eval_hw(self, hw):
         engine, best = self.engine, self.best
-        engine.strategy.evaluate_probe(engine, hw, engine.probe_seed(hw))
+        engine.evaluate_probe(hw, engine.probe_seed(hw))
         member_edps: list[float] = []
         maps, per_layer = {}, {}
         for layers, w in zip(self._member_layers, self._weights):
@@ -207,7 +207,7 @@ def make_portfolio_engine(config: CodesignConfig | None = None,
                           executor=None) -> CodesignEngine:
     """`CodesignEngine` prepared for portfolio search: validates
     `hw.prune == "off"` and upgrades a resolved "sequential" strategy to the
-    bit-identical "layer_batched" (see module docstring)."""
+    bit-identical "speculative" (see module docstring)."""
     cfg = config if config is not None else CodesignConfig()
     if cfg.hw.prune != "off":
         raise ValueError(
@@ -216,7 +216,7 @@ def make_portfolio_engine(config: CodesignConfig | None = None,
     if cfg.engine.resolve_strategy() == "sequential":
         cfg = dataclasses.replace(
             cfg, engine=dataclasses.replace(cfg.engine,
-                                            strategy="layer_batched"))
+                                            strategy="speculative"))
     return CodesignEngine(cfg, executor=executor)
 
 

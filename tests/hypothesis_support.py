@@ -43,10 +43,10 @@ engine_sections = st.fixed_dictionaries(
     {},
     optional=dict(
         backend=st.sampled_from([None, *BACKENDS]),
-        # probe_fanout/speculative require use_cache=True (validated at
-        # construction); the valid-config strategy respects that coupling.
+        # speculative requires use_cache=True (validated at construction);
+        # the valid-config strategy respects that coupling.
         strategy=st.sampled_from([s for s in STRATEGIES
-                                  if s not in ("probe_fanout", "speculative")]),
+                                  if s != "speculative"]),
         gp_refit_every=st.integers(1, 8),
         hw_gp_refit_every=st.integers(1, 8),
         batched=st.booleans(),

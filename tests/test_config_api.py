@@ -1,24 +1,23 @@
-"""The typed config API (ISSUE 4): `CodesignConfig` construction/validation/
-JSON round-trip, the `codesign(**legacy_kwargs)` deprecation shim's pinned
-result parity against `CodesignEngine(config).run()` on both backends, and the
-`probe_fanout` strategy's exact reproduction of the sequential outer-loop
-warmup (same seeds -> same probes, same EDPs, same histories).
+"""The typed config API: `CodesignConfig` construction/validation/JSON
+round-trip, "auto" resolved from the backend, the benchmark configurations
+loading through `CodesignConfig.from_dict`, and the warmup fan-out's exact
+reproduction of the sequential outer-loop warmup (same seeds -> same probes,
+same EDPs, same histories).
 
 Budgets stay inside the stacked GP's Cholesky regime (see
-tests/test_layer_batch.py), where all strategies are bit-identical.
+tests/test_layer_batch.py), where both probe paths are bit-identical.
 """
 
-import dataclasses
 import json
+import pathlib
 
 import numpy as np
 import pytest
 
 from repro.core import (CodesignConfig, CodesignEngine, EngineConfig,
                         HWSearchConfig, SWSearchConfig, bo_maximize,
-                        bo_maximize_many, codesign, config_from_legacy_kwargs,
-                        optimize_software, optimize_software_fanout)
-from repro.core.nested import PROBE_STRATEGIES
+                        bo_maximize_many, optimize_software,
+                        optimize_software_fanout)
 from repro.core.swspace import SoftwareSpace
 from repro.timeloop import MODEL_LAYERS, eyeriss_168
 from repro.timeloop import batch as tlb
@@ -26,11 +25,17 @@ from repro.timeloop import batch_jax as jtlb
 from repro.timeloop.arch import sample_hardware_pool
 
 
-def small_config(strategy="auto", backend=None, **top):
+BENCH_CONFIGS = sorted(
+    (pathlib.Path(__file__).resolve().parent.parent / "bench" / "configs")
+    .glob("*.json"))
+
+
+def small_config(strategy="auto", backend=None, spec_k=4, **top):
     # 3 warmup probes (the fan-out) + 1 scored trial (the per-probe path).
     return CodesignConfig(
         sw=SWSearchConfig(n_trials=12, n_warmup=6, pool_size=20),
-        hw=HWSearchConfig(n_trials=4, n_warmup=3, pool_size=20),
+        hw=HWSearchConfig(n_trials=4, n_warmup=3, pool_size=20,
+                          spec_k=spec_k),
         engine=EngineConfig(backend=backend, strategy=strategy),
         **top)
 
@@ -42,7 +47,7 @@ def test_json_round_trip():
     cfg = CodesignConfig(
         sw=SWSearchConfig(n_trials=42, acquisition="ei", lam=0.5),
         hw=HWSearchConfig(n_trials=7, num_pes=256, surrogate="gp_se"),
-        engine=EngineConfig(backend="jax", strategy="probe_fanout",
+        engine=EngineConfig(backend="jax", strategy="speculative",
                             gp_refit_every=3, pallas_mode="interpret"),
         seed=11, verbose=True)
     d = json.loads(json.dumps(cfg.to_dict()))  # through real JSON
@@ -64,9 +69,11 @@ def test_from_dict_partial_and_defaults():
     lambda: HWSearchConfig(num_pes=-1),
     lambda: EngineConfig(backend="torch"),
     lambda: EngineConfig(strategy="fanout"),
+    lambda: EngineConfig(strategy="layer_batched"),  # folded into speculative
+    lambda: EngineConfig(strategy="probe_fanout"),   # speculative, spec_k=1
     lambda: EngineConfig(pallas_mode="triton"),
     lambda: EngineConfig(gp_refit_every=0),
-    lambda: EngineConfig(strategy="probe_fanout", use_cache=False),
+    lambda: EngineConfig(strategy="speculative", use_cache=False),
     lambda: CodesignConfig.from_dict({"sw": {"n_trial": 5}}),  # typo'd field
     lambda: CodesignConfig(sw=HWSearchConfig()),  # wrong section type
 ])
@@ -85,33 +92,6 @@ def test_space_validation_shares_the_choice_site():
                       pallas_mode="triton")
 
 
-def test_legacy_kwarg_mapping():
-    cfg = config_from_legacy_kwargs(
-        n_hw_trials=5, n_sw_trials=30, n_sw_warmup=10, sw_pool=40, hw_pool=50,
-        num_pes=256, acquisition="ei", lam=2.0, surrogate="gp_se",
-        backend="jax", layer_batched=True, gp_refit_every=2, seed=3,
-        verbose=True)
-    assert cfg.hw.n_trials == 5 and cfg.hw.pool_size == 50
-    assert cfg.sw.n_trials == 30 and cfg.sw.n_warmup == 10
-    assert cfg.sw.acquisition == cfg.hw.acquisition == "ei"
-    assert cfg.sw.lam == cfg.hw.lam == 2.0
-    assert cfg.hw.num_pes == 256
-    assert cfg.engine.strategy == "layer_batched"
-    assert cfg.engine.gp_refit_every == 2
-    assert cfg.seed == 3 and cfg.verbose
-    assert config_from_legacy_kwargs(layer_batched=None).engine.strategy == "auto"
-    assert config_from_legacy_kwargs(layer_batched=False).engine.strategy == "sequential"
-    with pytest.raises(TypeError):
-        config_from_legacy_kwargs(n_trials=5)  # not a legacy codesign kwarg
-
-
-# --- legacy shim parity ---------------------------------------------------------
-
-
-LEGACY = dict(n_hw_trials=4, n_hw_warmup=3, hw_pool=20, n_sw_trials=12,
-              n_sw_warmup=6, sw_pool=20, seed=0)
-
-
 def _assert_codesign_parity(a, b):
     assert a.best_hw == b.best_hw
     assert a.best_model_edp == b.best_model_edp
@@ -120,60 +100,65 @@ def _assert_codesign_parity(a, b):
     assert a.hw_result.n_infeasible == b.hw_result.n_infeasible
 
 
-@pytest.mark.parametrize("backend", ["numpy", "jax"])
-def test_legacy_shim_matches_engine(backend):
-    """Seeded `codesign(**legacy_kwargs)` (DeprecationWarning) and
-    `CodesignEngine(config).run()` produce identical best-EDP/history."""
-    layers = MODEL_LAYERS["dqn"]
-    with pytest.deprecated_call():
-        old = codesign(layers, backend=backend, **LEGACY)
-    new = CodesignEngine(small_config(backend=backend)).run(layers)
-    _assert_codesign_parity(old, new)
-    # the blessed non-deprecated spellings
-    via_config = codesign(layers, config=small_config(backend=backend))
-    _assert_codesign_parity(via_config, new)
-    with pytest.raises(TypeError):
-        codesign(layers, config=small_config(), n_hw_trials=3)  # not both
-
-
 # --- probe fan-out --------------------------------------------------------------
 
 
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_probe_fanout_matches_sequential_warmup(backend):
-    """The H*L*B stacked warmup fan-out reproduces the sequential outer-loop
-    warmup exactly: same probes, same per-probe EDPs, same histories."""
+    """The H*L*B stacked warmup fan-out (`spec_k=1`: no speculation past the
+    warmup) reproduces the sequential outer-loop warmup exactly: same probes,
+    same per-probe EDPs, same histories."""
     layers = MODEL_LAYERS["dqn"]
     results = {}
-    for strategy in ("sequential", "layer_batched", "probe_fanout"):
-        eng = CodesignEngine(small_config(strategy=strategy, backend=backend))
+    for strategy in ("sequential", "speculative"):
+        eng = CodesignEngine(small_config(strategy=strategy, backend=backend,
+                                          spec_k=1))
         results[strategy] = eng.run(layers)
         assert eng.strategy_name == strategy
-    _assert_codesign_parity(results["probe_fanout"], results["sequential"])
-    _assert_codesign_parity(results["probe_fanout"], results["layer_batched"])
+    _assert_codesign_parity(results["speculative"], results["sequential"])
+    assert results["speculative"].stats["spec_evaluated"] == 0
 
 
-def test_probe_fanout_prefills_cache_for_all_warmup_probes():
+def test_probe_fanout_prefills_cache_for_all_warmup_probes(monkeypatch):
     """After the warmup round every (probe, layer) pair the fan-out searched
     is a cache hit -- eval_hw never re-runs an inner search for them."""
     layers = MODEL_LAYERS["mlp"]
-    eng = CodesignEngine(small_config(strategy="probe_fanout"))
+    eng = CodesignEngine(small_config(strategy="speculative", spec_k=1))
     seen = []
-    orig = PROBE_STRATEGIES["probe_fanout"].evaluate_probe
+    orig = CodesignEngine.evaluate_probe
 
-    def spying(self, engine, hw, seed):
-        before = set(engine.cache)
-        orig(self, engine, hw, seed)
-        seen.append(set(engine.cache) - before)
+    def spying(self, hw, seed):
+        before = set(self.cache)
+        orig(self, hw, seed)
+        seen.append(set(self.cache) - before)
 
-    PROBE_STRATEGIES["probe_fanout"].evaluate_probe = spying
-    try:
-        eng.run(layers)
-    finally:
-        PROBE_STRATEGIES["probe_fanout"].evaluate_probe = orig
+    monkeypatch.setattr(CodesignEngine, "evaluate_probe", spying)
+    eng.run(layers)
     n_warm = eng.config.hw.n_warmup
     assert len(seen) == eng.config.hw.n_trials
     assert all(not new for new in seen[:n_warm])  # warmup: all cache hits
+
+
+@pytest.mark.parametrize("backend,expected", [("numpy", "sequential"),
+                                              ("jax", "speculative")])
+def test_auto_strategy_resolves_per_backend(backend, expected):
+    """"auto" is the choice made from what the engine observes: the stacked
+    speculative path on jax, the per-layer sequential path on numpy, and
+    sequential wherever the cache the fan-outs fill is off."""
+    assert EngineConfig(backend=backend).resolve_strategy() == expected
+    assert EngineConfig(backend=backend,
+                        use_cache=False).resolve_strategy() == "sequential"
+    assert CodesignEngine(small_config(backend=backend)).strategy_name == expected
+
+
+@pytest.mark.parametrize("path", BENCH_CONFIGS, ids=lambda p: p.stem)
+def test_bench_configs_load_on_the_speculative_path(path):
+    """Every benchmark configuration's `codesign` section still loads, and
+    runs the speculative path at `spec_k` 4."""
+    cfg = CodesignConfig.from_dict(json.loads(path.read_text())["codesign"])
+    assert cfg.engine.strategy == "speculative"
+    assert cfg.engine.resolve_strategy() == "speculative"
+    assert cfg.hw.spec_k == 4
 
 
 def test_optimize_software_fanout_matches_per_probe():
@@ -244,14 +229,11 @@ def test_optimize_software_config_equals_kwargs():
 
 
 def test_positional_legacy_callers_break_loudly():
-    """Pre-config POSITIONAL callers (codesign(layers, 256),
-    optimize_software(hw, layer, 100), bo_maximize(space, 100)) bind to the
-    new config parameter; they get a descriptive TypeError at the entry
-    point, not a deep AttributeError."""
+    """Pre-config POSITIONAL callers (optimize_software(hw, layer, 100),
+    bo_maximize(space, 100)) bind to the new config parameter; they get a
+    descriptive TypeError at the entry point, not a deep AttributeError."""
     hw = eyeriss_168()
     layer = MODEL_LAYERS["dqn"][0]
-    with pytest.raises(TypeError, match="CodesignConfig"):
-        codesign(MODEL_LAYERS["dqn"], 256)
     with pytest.raises(TypeError, match="SearchConfig"):
         optimize_software(hw, layer, 100)
     with pytest.raises(TypeError, match="SearchConfig"):

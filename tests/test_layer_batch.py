@@ -21,7 +21,10 @@ import pytest
 from repro.core.bo import BOResult, bo_maximize, bo_maximize_many
 from repro.core.gp import GP, GPClassifier, GPClassifierStack, GPStack
 from repro.core.hwspace import HardwareSpace
-from repro.core.nested import codesign, optimize_software, optimize_software_many
+from repro.core.config import (CodesignConfig, EngineConfig, HWSearchConfig,
+                               SWSearchConfig)
+from repro.core.nested import (CodesignEngine, optimize_software,
+                               optimize_software_many)
 from repro.core.swspace import LayerStackSpace, SoftwareSpace
 from repro.timeloop import MODEL_LAYERS, eyeriss_168
 from repro.timeloop import batch as tlb
@@ -63,15 +66,24 @@ def test_layer_batched_matches_sequential_jax(model):
         _assert_run_parity(rs, rm, "jax")
 
 
+def _codesign(layers, n_hw, n_sw, n_sw_warmup, pool, seed, strategy="auto",
+              **engine):
+    config = CodesignConfig(
+        sw=SWSearchConfig(n_trials=n_sw, n_warmup=n_sw_warmup, pool_size=pool),
+        hw=HWSearchConfig(n_trials=n_hw, pool_size=pool),
+        engine=EngineConfig(strategy=strategy, **engine), seed=seed)
+    return CodesignEngine(config).run(layers)
+
+
 def test_codesign_layer_batched_identical_to_sequential():
-    """`codesign(layer_batched=True)` collapses eval_hw's layer loop into one
+    """The speculative path collapses eval_hw's layer loop into one
     bo_maximize_many call per probe; with the shared (hw, layer) cache the
     whole nested search must land on the same design as the sequential path."""
     layers = MODEL_LAYERS["dqn"]
-    kw = dict(n_hw_trials=3, n_sw_trials=12, n_sw_warmup=6, sw_pool=20,
-              hw_pool=20, seed=0, backend="numpy")
-    r_seq = codesign(layers, layer_batched=False, **kw)
-    r_lb = codesign(layers, layer_batched=True, **kw)
+    kw = dict(n_hw=3, n_sw=12, n_sw_warmup=6, pool=20, seed=0,
+              backend="numpy")
+    r_seq = _codesign(layers, strategy="sequential", **kw)
+    r_lb = _codesign(layers, strategy="speculative", **kw)
     assert r_lb.best_hw == r_seq.best_hw
     assert r_lb.best_model_edp == r_seq.best_model_edp
     assert r_lb.best_mappings == r_seq.best_mappings
@@ -79,14 +91,13 @@ def test_codesign_layer_batched_identical_to_sequential():
 
 
 def test_codesign_layer_batched_defaults_by_backend():
-    """layer_batched=None resolves to the backend: on for jax, off for numpy
-    (the numpy default keeps the sequential path; forcing True works too)."""
+    """strategy="auto" resolves to the backend: speculative on jax,
+    sequential on numpy (forcing speculative on jax gives the same run)."""
     layers = MODEL_LAYERS["dqn"]
-    kw = dict(n_hw_trials=2, n_sw_trials=10, n_sw_warmup=5, sw_pool=16,
-              hw_pool=16, seed=1)
-    r = codesign(layers, backend="jax", **kw)  # auto layer-batched
+    kw = dict(n_hw=2, n_sw=10, n_sw_warmup=5, pool=16, seed=1, backend="jax")
+    r = _codesign(layers, **kw)  # auto -> speculative
     assert r.best_hw is not None and np.isfinite(r.best_model_edp)
-    r2 = codesign(layers, backend="jax", layer_batched=True, **kw)
+    r2 = _codesign(layers, strategy="speculative", **kw)
     assert r2.best_model_edp == r.best_model_edp
 
 
@@ -459,9 +470,8 @@ def test_gp_refit_every_parity_and_threading():
     for rs, rm in zip(seq, many):
         assert rm.best_point == rs.best_point
         assert np.array_equal(rm.history, rs.history)
-    r = codesign(MODEL_LAYERS["dqn"], n_hw_trials=2, n_sw_trials=10,
-                 n_sw_warmup=5, sw_pool=16, hw_pool=16, seed=0,
-                 gp_refit_every=3, backend="numpy")
+    r = _codesign(MODEL_LAYERS["dqn"], n_hw=2, n_sw=10, n_sw_warmup=5,
+                  pool=16, seed=0, gp_refit_every=3, backend="numpy")
     assert np.isfinite(r.best_model_edp)
 
 

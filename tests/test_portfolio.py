@@ -70,9 +70,9 @@ def test_portfolio_requires_prune_off():
 
 def test_portfolio_upgrades_sequential_strategy():
     # tiny_config resolves strategy "auto" -> "sequential" on numpy; the
-    # factory upgrades it to the bit-identical layer_batched...
+    # factory upgrades it to the bit-identical speculative...
     engine = make_portfolio_engine(tiny_config())
-    assert engine.strategy_name == "layer_batched"
+    assert engine.strategy_name == "speculative"
     # ...and the session refuses a sequential engine outright.
     seq_cfg = dataclasses.replace(
         tiny_config(), engine=EngineConfig(backend="numpy",

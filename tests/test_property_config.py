@@ -107,7 +107,7 @@ def test_prune_and_rank1_round_trip(mode, margin, rank1):
     assert back.engine.gp_rank1_updates == rank1
 
 
-@given(st.sampled_from(["probe_fanout", "speculative"]))
+@given(st.sampled_from(["speculative"]))
 @settings(max_examples=4, deadline=None)
 def test_fanout_strategies_require_cache(strategy):
     with pytest.raises(ValueError, match="use_cache"):

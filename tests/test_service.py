@@ -27,19 +27,20 @@ import pytest
 
 from repro.core import (CodesignConfig, CodesignEngine, EngineConfig,
                         HWSearchConfig, LRUCache, ServiceConfig,
-                        SWSearchConfig, SearchSession, codesign)
+                        SWSearchConfig, SearchSession)
 from repro.core import nested as nested_mod
 from repro.service import (CodesignService, DesignStore, ServiceRequest,
                            design_key)
 from repro.timeloop import MODEL_LAYERS
 
 
-def svc_config(seed=0, strategy="sequential", n_hw=4, **eng):
+def svc_config(seed=0, strategy="sequential", n_hw=4, spec_k=2, **eng):
     # sw n_trials=12 keeps every stacked GP fit in the Cholesky regime where
     # cross-request stacking is bit-identical to standalone searches.
     return CodesignConfig(
         sw=SWSearchConfig(n_trials=12, n_warmup=5, pool_size=15),
-        hw=HWSearchConfig(n_trials=n_hw, n_warmup=2, pool_size=15, spec_k=2),
+        hw=HWSearchConfig(n_trials=n_hw, n_warmup=2, pool_size=15,
+                          spec_k=spec_k),
         engine=EngineConfig(strategy=strategy, **eng),
         seed=seed)
 
@@ -47,8 +48,8 @@ def svc_config(seed=0, strategy="sequential", n_hw=4, **eng):
 MIXED_REQUESTS = [  # mixed workloads x strategies x seeds
     ("dqn", svc_config(0, "sequential")),
     ("mlp", svc_config(1, "speculative")),
-    ("dqn", svc_config(2, "layer_batched")),
-    ("mlp", svc_config(3, "probe_fanout")),
+    ("dqn", svc_config(2, "auto")),
+    ("mlp", svc_config(3, "speculative", spec_k=1)),
 ]
 
 
@@ -406,31 +407,6 @@ def test_pending_is_trajectory_neutral():
         if not session.step():
             break
     _assert_parity(session.result(), ref)
-
-
-# --- legacy shim ------------------------------------------------------------------
-
-
-def test_legacy_shim_routes_through_search_session():
-    """codesign(**legacy_kwargs) emits ONE consolidated DeprecationWarning and
-    drives the same SearchSession machinery as the config API."""
-    sessions = []
-    orig = nested_mod.SearchSession
-
-    class SpySession(orig):
-        def __init__(self, *a, **kw):
-            sessions.append(self)
-            super().__init__(*a, **kw)
-
-    nested_mod.SearchSession = SpySession
-    try:
-        with pytest.warns(DeprecationWarning) as record:
-            codesign(MODEL_LAYERS["dqn"], n_hw_trials=3, n_hw_warmup=2,
-                     n_sw_trials=10, n_sw_warmup=4, sw_pool=15, hw_pool=15)
-    finally:
-        nested_mod.SearchSession = orig
-    assert len(record) == 1  # one consolidated warning
-    assert len(sessions) == 1  # the run was the session, stepped through
 
 
 # --- config + request surface -----------------------------------------------------

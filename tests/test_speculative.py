@@ -1,4 +1,4 @@
-"""The speculative outer loop (ISSUE 5): `strategy="speculative"` must be
+"""The speculative outer loop: `strategy="speculative"` must be
 bit-identical to `strategy="sequential"` -- same best hardware, same best
 mappings, same outer BO history -- on BOTH backends, for all four seed
 workloads, because speculation only moves inner-search work earlier, never
@@ -26,7 +26,6 @@ import pytest
 from repro.core import (CodesignConfig, CodesignEngine, EngineConfig,
                         HWSearchConfig, SWSearchConfig, score_topk)
 from repro.core import nested as nested_mod
-from repro.core.nested import PROBE_STRATEGIES
 from repro.timeloop import MODEL_LAYERS
 
 def spec_config(strategy="speculative", backend=None, hw_stride=1,
@@ -146,8 +145,8 @@ def _spied_run(config, layers):
     probes = []
     orig_fanout = nested_mod.optimize_software_fanout
     orig_many = nested_mod.optimize_software_many
-    orig_topk = PROBE_STRATEGIES["speculative"].prefetch_topk
-    orig_eval = PROBE_STRATEGIES["speculative"].evaluate_probe
+    orig_topk = CodesignEngine.prefetch_topk
+    orig_eval = CodesignEngine.evaluate_probe
 
     def spy_fanout(items, *a, **kw):
         searched.extend(items)
@@ -157,31 +156,31 @@ def _spied_run(config, layers):
         searched.extend((hw, layer) for layer in todo)
         return orig_many(hw, todo, *a, **kw)
 
-    def spy_topk(self, engine, cands):
-        before = set(engine.cache)
-        orig_topk(self, engine, cands)
+    def spy_topk(self, cands):
+        before = set(self.cache)
+        orig_topk(self, cands)
         speculated.append({
             "argmax": cands[0],
-            "filled_hw": {hw for hw, _ in set(engine.cache) - before},
+            "filled_hw": {hw for hw, _ in set(self.cache) - before},
         })
 
-    def spy_eval(self, engine, hw, seed):
+    def spy_eval(self, hw, seed):
         # the flag the engine's own hit accounting is about to read
-        probes.append((hw, hw in engine._speculated))
-        orig_eval(self, engine, hw, seed)
+        probes.append((hw, hw in self._speculated))
+        orig_eval(self, hw, seed)
 
     nested_mod.optimize_software_fanout = spy_fanout
     nested_mod.optimize_software_many = spy_many
-    PROBE_STRATEGIES["speculative"].prefetch_topk = spy_topk
-    PROBE_STRATEGIES["speculative"].evaluate_probe = spy_eval
+    CodesignEngine.prefetch_topk = spy_topk
+    CodesignEngine.evaluate_probe = spy_eval
     try:
         eng = CodesignEngine(config)
         result = eng.run(layers)
     finally:
         nested_mod.optimize_software_fanout = orig_fanout
         nested_mod.optimize_software_many = orig_many
-        PROBE_STRATEGIES["speculative"].prefetch_topk = orig_topk
-        PROBE_STRATEGIES["speculative"].evaluate_probe = orig_eval
+        CodesignEngine.prefetch_topk = orig_topk
+        CodesignEngine.evaluate_probe = orig_eval
     return eng, result, searched, speculated, probes
 
 
@@ -216,7 +215,7 @@ def test_speculative_hits_skip_reevaluation():
 
 
 def test_non_speculative_strategies_report_zero_spec_stats():
-    r = CodesignEngine(spec_config("layer_batched",
+    r = CodesignEngine(spec_config("sequential",
                                    backend="numpy")).run(MODEL_LAYERS["dqn"])
     expected = {"spec_evaluated": 0, "spec_hits": 0, "spec_hit_rate": 0.0,
                 "prune_considered": 0, "prune_pruned": 0,
