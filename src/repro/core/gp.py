@@ -53,9 +53,14 @@ from repro.core import trace
 _JITTER = 1e-6
 _PAD_NOISE = 1e6  # effective infinite noise on padded rows -> zero influence
 # Stacked linear-kernel fits switch to the O(n d^2) Woodbury NLL above this
-# many (padded) data rows; below it the O(n^3) Cholesky NLL is cheap and keeps
-# the stacked fit within f64 roundoff of the sequential one (see `_fit_stack`).
-_LOWRANK_MIN_ROWS = 32
+# many (padded) data rows (`_fit_lowrank`).  One blocking fit on the host's
+# CPU (80 Adam steps, d = 14, float64) costs less through Woodbury at every
+# stack width from bucket 24 up; at bucket 16 the two NLLs cost about the
+# same and Cholesky is the cheaper for narrow stacks.  Up to 16 rows the
+# Cholesky NLL keeps the stacked fit within f64 roundoff of the sequential
+# one, so lockstep and speculative searches at small budgets reproduce
+# sequential ones exactly (see `_fit_stack`).
+_LOWRANK_MIN_ROWS = 16
 
 
 def _bucket(n: int) -> int:
@@ -500,20 +505,27 @@ def _fit_stack(params, X, y, mask, kind, steps, train_tau):
     the single-run `_fit` to f64 roundoff rather than bit for bit.
 
     Above `_LOWRANK_MIN_ROWS` data rows the linear kernel (the objective
-    surrogate) fits through the Woodbury NLL (`lowrank=True`): the per-trial
+    surrogate) fits through the Woodbury NLL (`_fit_lowrank`): the per-trial
     refit is the layer-batched search's dominant cost, and the low-rank form
-    cuts it from O(n^3) to O(n d^2) per Adam step.  It computes the same NLL
-    to f64 roundoff, but through the ill-conditioned quad-term subtraction its
+    cuts it from O(n^3) to O(n d^2) per Adam step, the cheaper of the two at
+    every stack width from 24 rows up.  It computes the same NLL to f64
+    roundoff, but through the ill-conditioned quad-term subtraction its
     gradients drift from the Cholesky path's by ~1e-8 relative, which after 80
     Adam steps perturbs the posterior at the ~1e-7 level -- statistically
-    nothing, but further from the sequential fits than the small buckets
-    keep (the bucket is a static shape, so the switch is deterministic and
-    visible in the jit cache)."""
-    lowrank = kind == "linear" and X.shape[1] > _LOWRANK_MIN_ROWS
+    nothing, but further from the sequential fits than the buckets of 8 and
+    16 rows keep (the bucket is a static shape, so the switch is
+    deterministic and visible in the jit cache)."""
+    lowrank = _fit_lowrank(kind, X.shape[1])
     return jax.vmap(
         lambda p, xx, yy, mm: _fit(p, xx, yy, mm, kind, steps, 0.05,
                                    train_tau, lowrank=lowrank)
     )(params, X, y, mask)
+
+
+def _fit_lowrank(kind: str, rows: int) -> bool:
+    """Whether a stacked fit of `rows` padded data rows fits through the
+    Woodbury NLL: the linear kernel above `_LOWRANK_MIN_ROWS` rows."""
+    return kind == "linear" and rows > _LOWRANK_MIN_ROWS
 
 
 @functools.partial(jax.jit, static_argnames=("kind",))
@@ -529,11 +541,13 @@ def _bucket_stack(n: int) -> int:
     """Finer-grained buckets for the stacked fit: multiples of 8 up to 64
     rows, multiples of 32 beyond.  The multi-run surrogate refit dominates the
     layer-batched search's per-trial cost, so the padding waste of
-    power-of-two buckets (rows up to 2x -> Cholesky flops up to 8x just below
-    a boundary) costs more than the extra compile-cache entries.  Padding
-    rows are exactly zero-influence (see module docstring), so the bucket
-    choice is purely a flops/compile-count tradeoff -- results are
-    unchanged."""
+    power-of-two buckets (rows up to 2x -> Woodbury flops up to 2x and
+    Cholesky flops up to 8x just below a boundary) costs more than the extra
+    compile-cache entries.  The bucket also picks the linear fit's NLL:
+    Cholesky at 8 and 16 rows, Woodbury from 24 up (`_fit_lowrank`).
+    Padding rows are exactly zero-influence (see module docstring), so the
+    bucket choice is otherwise purely a flops/compile-count tradeoff --
+    results are unchanged."""
     if n <= 8:
         return 8
     step = 8 if n <= 64 else 32
@@ -646,6 +660,8 @@ class GPStack:
         self._params_on_host = host is not None
         if self._params_on_host:
             trace.COUNTERS["gp.host_fits"] += 1
+        if _fit_lowrank(self.kind, b):
+            trace.COUNTERS["gp.lowrank_fits"] += 1
         return self
 
     def _fitted(self) -> tuple:

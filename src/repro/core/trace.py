@@ -22,6 +22,9 @@ difference of two snapshots is the reading over the stretch between them.
                        (`gp._fit_device`: where the default backend is a
                        TPU); their copies onto the CPU device are host
                        memory and not in transfer.h2d_bytes
+  gp.lowrank_fits      stacked fits through the Woodbury NLL
+                       (`gp._fit_lowrank`: the linear kernel above
+                       `gp._LOWRANK_MIN_ROWS` padded rows)
   transfer.h2d_bytes   bytes copied to the device for the search's programs
                        (`to_device`)
   transfer.d2h_bytes   bytes of device results fetched to the host (`fetch`)

@@ -32,7 +32,8 @@ from repro.timeloop import batch_jax as jtlb
 
 MODELS = ("resnet", "dqn", "mlp", "transformer")
 # Budgets chosen to stay inside the stacked fit's Cholesky regime
-# (<= gp._LOWRANK_MIN_ROWS data rows), where lockstep == sequential exactly.
+# (<= gp._LOWRANK_MIN_ROWS = 16 padded data rows: 14 trials), where
+# lockstep == sequential exactly.
 KW = dict(n_trials=14, n_warmup=6, pool_size=20, seed=3)
 
 
@@ -186,12 +187,17 @@ def test_gp_stack_matches_individual_fits():
                 np.testing.assert_allclose(var_s[k], var, atol=1e-8)
 
 
-def test_gp_stack_lowrank_regime_close_to_cholesky():
+@pytest.mark.parametrize("sizes", [(27, 32), (40, 52)],
+                         ids=["bucket-32", "bucket-56"])
+def test_gp_stack_lowrank_regime_close_to_cholesky(sizes):
     """Above the row threshold the linear-kernel stack fits through the
     Woodbury NLL; the posterior agrees with the Cholesky fit to far below
     anything an acquisition argmax can resolve at those data sizes."""
+    from repro.core import gp
+
     rng = np.random.default_rng(1)
-    Xs = [rng.normal(size=(n, 6)) for n in (40, 52)]   # > _LOWRANK_MIN_ROWS
+    Xs = [rng.normal(size=(n, 6)) for n in sizes]
+    assert gp._fit_lowrank("linear", gp._bucket_stack(max(sizes)))
     ys = [X @ rng.normal(size=6) + 0.05 * rng.normal(size=len(X)) for X in Xs]
     stack = GPStack(kind="linear", noisy=False).fit(Xs, ys)
     pools = np.stack([rng.normal(size=(7, 6)) for _ in Xs])
@@ -226,13 +232,15 @@ def _fit_and_score_stack(X, y, mask, pools):
                 np.asarray(mu), np.asarray(var))
 
 
-@pytest.mark.parametrize("bucket, atol", [(32, 1e-10), (40, 1e-8)],
-                         ids=["cholesky", "woodbury"])
+@pytest.mark.parametrize("bucket, atol",
+                         [(16, 1e-10), (32, 1e-8), (40, 1e-8)],
+                         ids=["cholesky", "woodbury-32", "woodbury"])
 def test_gp_stack_runs_fit_alone_as_in_the_batch(bucket, atol):
     """Batching the run axis couples no runs: each run of a ragged 16-run
     linear stack, fit alone in a stack of 1 at the same bucket, gives the
     same hyperparameters and posterior; so does the stack in reverse order.
-    Bucket 32 fits through the Cholesky NLL, bucket 40 through Woodbury."""
+    Bucket 16 fits through the Cholesky NLL, buckets 32 and 40 through
+    Woodbury."""
     from repro.core import gp
 
     rng = np.random.default_rng(4)
@@ -243,7 +251,7 @@ def test_gp_stack_runs_fit_alone_as_in_the_batch(bucket, atol):
     ys = [X @ rng.normal(size=d) + 0.05 * rng.normal(size=len(X)) for X in Xs]
     X, y, mask = gp._pad_runs(Xs, ys)
     assert X.shape[1] == bucket
-    assert (bucket > gp._LOWRANK_MIN_ROWS) == (bucket == 40)
+    assert gp._fit_lowrank("linear", bucket) == (bucket > 16)
     pools = rng.normal(size=(L, P, d))
     params, mu, var = _fit_and_score_stack(X, y, mask, pools)
     rev = slice(None, None, -1)

@@ -9,8 +9,8 @@ changes it.  Two properties make that exact and are covered here:
   * the prefetch hook is a pure observer of the scored trial's acquisition
     ranking (no RNG consumed, argmax selection untouched).
 
-Budgets stay inside the stacked GP's Cholesky regime (sw n_trials=14, well
-under `gp._LOWRANK_MIN_ROWS=32` feasible rows -- see tests/test_layer_batch.py)
+Budgets stay inside the stacked GP's Cholesky regime (sw n_trials=14, under
+`gp._LOWRANK_MIN_ROWS=16` padded rows -- see tests/test_layer_batch.py)
 where stacked fan-out searches are bit-identical to sequential ones.
 
 The cache-spy tests pin the speculation machinery itself: speculative hits

@@ -79,8 +79,8 @@ def _stack_params(one_chip, runs):
 
 
 # (4, 256): the inner search's last bucket at the paper's 250 trials;
-# (16, 32) and (16, 40): the ResNet cell's widest stacks, Cholesky and
-# Woodbury; (60, 40): the Moonlight decode cell's (4 probes x 15 layers).
+# (16, 32) and (16, 40): the ResNet cell's widest stacks, both through the
+# Woodbury NLL; (60, 40): the Moonlight decode cell's (4 probes x 15 layers).
 @pytest.mark.parametrize("runs, bucket", [(4, STACK_BUCKET), (16, 32),
                                           (16, 40), (60, 40)])
 def test_gp_stack_fit_compiles_for_v5e_in_f64(one_chip, runs, bucket):

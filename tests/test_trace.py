@@ -148,6 +148,23 @@ def test_gp_fit_counters_read_the_stack_width():
     assert (got["gp.fits"], got["gp.runs"]) == (1, 2)
 
 
+@pytest.mark.parametrize("kind, rows, lowrank",
+                         [("linear", 20, 1), ("linear", 37, 1),
+                          ("linear", 13, 0), ("se", 20, 0)],
+                         ids=["linear-bucket-24", "linear-bucket-40",
+                              "linear-bucket-16", "se-bucket-24"])
+def test_gp_lowrank_fits_count_the_woodbury_fits(kind, rows, lowrank):
+    """gp.lowrank_fits counts the stacked fits through the Woodbury NLL: a
+    linear stack padded to 24 rows or more, not one padded to 16 nor an
+    SE-kernel stack; gp.fits counts every one."""
+    rng = np.random.default_rng(2)
+    X = [rng.normal(size=(n, 6)) for n in (rows - 4, rows)]
+    before = trace.counters_snapshot()
+    GPStack(kind=kind).fit(X, [rng.normal(size=len(x)) for x in X])
+    got = counter_diff(before)
+    assert (got["gp.fits"], got.get("gp.lowrank_fits", 0)) == (1, lowrank)
+
+
 def test_stats_unchanged_by_the_counter_move():
     """The SlotCache tallies live in `trace.COUNTERS` now; a search's stats
     read as they did when `cache.py` held them (values of the tree before
